@@ -159,11 +159,29 @@ def pack_axis0(mask: jax.Array) -> jax.Array:
 def section_planes_packed(q: jax.Array, rows: int, cols: int) -> jax.Array:
     """int32[S*rows] magnitudes -> packed uint8[S, ceil(rows/8), cols] planes.
 
-    The canonical planner representation: one packbits per tensor, after
+    The canonical planner representation: one packing pass per tensor, after
     which all pricing (cost/schedule/stucking) runs on packed words.
     ``q`` must already be padded to a multiple of ``rows``.
+
+    Byte-identical to ``pack_rows(bitplanes(q.reshape(-1, rows), cols))``,
+    but computed on the transposed magnitudes ``[W, 8, S]``, whose long
+    section axis is minor: one reduction over the 8 rows of each byte builds
+    ``[W, cols, S]`` bytes, and one transpose returns the canonical layout.
+    The bool ``[S, rows, cols]`` planes and ``packbits``' ``[S, W, 8]``
+    regrouping would otherwise be materialized on TPU with their small minor
+    dims padded to full tiles (16x the magnitudes' bytes), which does not
+    fit one chip's memory for a full-width LM tensor.
     """
-    return pack_rows(bitplanes(q.reshape(-1, rows), cols))
+    qt = q.reshape(-1, rows).T
+    pad = (-rows) % 8
+    if pad:
+        qt = jnp.pad(qt, ((0, pad), (0, 0)))
+    qt = qt.reshape(-1, 8, qt.shape[1])  # [W, 8, S]: row 8w+j
+    col = jnp.arange(cols, dtype=qt.dtype)[None, None, :, None]
+    msb_first = (7 - jnp.arange(8, dtype=qt.dtype))[None, :, None, None]
+    bits = ((qt[:, :, None, :] >> col) & 1) << msb_first  # [W, 8, cols, S]
+    word = jnp.sum(bits.astype(jnp.uint8), axis=1, dtype=jnp.uint8)
+    return jnp.transpose(word, (2, 0, 1))
 
 
 @partial(jax.jit, static_argnames=("cols",))

@@ -635,7 +635,7 @@ class IntegrityManager:
         deployed = dict(plan.deployed)
         for name in self.tensors:
             if name in deployed:
-                deployed[name] = self.rebuild(name)
+                deployed[name] = np.asarray(self.rebuild(name))
         return dataclasses.replace(plan, deployed=deployed)
 
     # -- reporting ----------------------------------------------------------

@@ -119,7 +119,11 @@ class DeploymentPlan:
     spec: CrossbarSpec
     config: PlannerConfig
     reports: dict[str, TensorReport]
-    deployed: dict[str, jax.Array]  # name -> achieved weights (w_hat)
+    # name -> achieved weights (w_hat), held in host memory: the device
+    # copies are the serving operands deploy_params materializes, and dense
+    # f32 copies of every deployed tensor beside the planner's working set
+    # would not fit one chip for a full-width LM
+    deployed: dict[str, np.ndarray]
     pool_stats: dict | None = None  # wear summary when built against a CrossbarPool
 
     def totals(self) -> dict[str, float]:
@@ -656,6 +660,29 @@ def iter_weights(params: Any, config: PlannerConfig):
         yield name, leaf
 
 
+def _compile_prep_sizes(params: Any, spec: CrossbarSpec, config: PlannerConfig) -> None:
+    """Compile the pool path's per-size prep program for every distinct
+    tensor size at once, in threads, before tensors stream one by one.
+
+    The program holds a stable sort over the whole tensor, which the TPU
+    compiler takes most of a minute to build for each size, while the
+    compiler releases the GIL; a full-width LM has a handful of distinct
+    sizes.  The streaming loop's calls then find these executables in
+    JAX's compile cache.
+    """
+    sizes = sorted({int(np.prod(w.shape)) for _, w in iter_weights(params, config)})
+    if len(sizes) < 2:
+        return
+
+    def compile_one(n: int) -> None:
+        _prep_core_pool.lower(jax.ShapeDtypeStruct((n,), jnp.float32), spec, config).compile()
+
+    from concurrent.futures import ThreadPoolExecutor
+
+    with ThreadPoolExecutor(len(sizes)) as ex:
+        list(ex.map(compile_one, sizes))
+
+
 def build_deployment(
     params: Any,
     spec: CrossbarSpec = CrossbarSpec(),
@@ -674,16 +701,18 @@ def build_deployment(
     without a pool, so resetting the pool between tensors recovers the
     stateless plan bit-exactly.
     """
+    if pool is not None and config.impl == "packed":
+        _compile_prep_sizes(params, spec, config)
     key = jax.random.PRNGKey(config.seed)
     reports: dict[str, TensorReport] = {}
-    deployed: dict[str, jax.Array] = {}
+    deployed: dict[str, np.ndarray] = {}
     for name, w in iter_weights(params, config):
         key, sub = jax.random.split(key)
         if progress:
             progress(name)
         report, w_hat = analyze_tensor(w, spec, config, sub, name=name, pool=pool)
         reports[name] = report
-        deployed[name] = w_hat
+        deployed[name] = np.asarray(w_hat)
     return DeploymentPlan(
         spec=spec,
         config=config,
@@ -706,6 +735,8 @@ MATERIALIZE_DENSE_ONLY = (
     "a_log",         # Mamba state matrix (elementwise exp)
     "r",             # sLSTM recurrent kernel (per-head einsum)
     "meta",          # Hymba meta tokens (concatenated, never multiplied)
+    "g",             # norm gains (elementwise scale; layer-stacked gains
+                     # reach min_ndim/min_size at full width)
 )
 
 
@@ -760,7 +791,7 @@ def deploy_params(
         if name not in plan.deployed:
             out.append(leaf)
             continue
-        w_hat = plan.deployed[name]
+        w_hat = jnp.asarray(plan.deployed[name])
         if materialize == "dense" or _dense_only(name):
             out.append(w_hat)
             continue

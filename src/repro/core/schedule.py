@@ -128,10 +128,11 @@ def schedule_job_costs(
     prev, cur = chain_pairs(chains, include_initial=include_initial)
     if prev.shape[0] == 0:
         return jnp.zeros((0,), jnp.int32)
+    # Sections are gathered as flat byte rows: a gathered [T, W, cols] block
+    # would be laid out on TPU with its small minor dims padded to full tiles.
+    flat = packed.reshape(packed.shape[0], -1)
     # Prepend the pristine all-zero state so prev == -1 gathers zeros.
-    states = jnp.concatenate(
-        [jnp.zeros((1,) + packed.shape[1:], packed.dtype), packed], axis=0
-    )
+    states = jnp.concatenate([jnp.zeros((1, flat.shape[1]), flat.dtype), flat], axis=0)
     return hamming_ops.price_pairs(states[prev + 1], states[cur + 1])
 
 

@@ -15,29 +15,27 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels._util import default_interpret, on_tpu, pad_axis_to, round_up
+from repro.kernels._util import default_interpret, on_tpu, round_up
 from repro.kernels.hamming import ref as hamming_ref
 from repro.kernels.hamming.kernel import hamming_pairs_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
 def hamming_pairs(
-    a: jax.Array, b: jax.Array, *, bt: int = 256, interpret: bool | None = None
+    a: jax.Array, b: jax.Array, *, bt: int = 1024, interpret: bool | None = None
 ) -> jax.Array:
     """Per-pair transition counts: popcount(a[t] ^ b[t]) -> int32[T].
 
-    Zero-padding pairs is free (popcount(0^0) = 0) so arbitrary T is fine.
+    Arbitrary T: a T no larger than ``bt`` is one whole-array block, and a
+    larger T runs ragged ``bt``-row blocks (the kernel drops rows past T).
     """
     if a.shape != b.shape:
         raise ValueError(f"shape mismatch: {a.shape} vs {b.shape}")
     t = a.shape[0]
-    interp = default_interpret(interpret)
-    bt_ = min(bt, round_up(max(t, 1), 8))
-    tp = round_up(max(t, 1), bt_)
-    ap = pad_axis_to(a, 0, tp)
-    bp = pad_axis_to(b, 0, tp)
-    out = hamming_pairs_kernel(ap, bp, bt=bt_, interpret=interp)
-    return out[:t]
+    if t == 0:
+        return jnp.zeros((0,), jnp.int32)
+    bt_ = t if t <= bt else round_up(bt, 32)
+    return hamming_pairs_kernel(a, b, bt=bt_, interpret=default_interpret(interpret))
 
 
 def chain_costs(packed_states: jax.Array, *, interpret: bool | None = None) -> jax.Array:
@@ -48,7 +46,8 @@ def chain_costs(packed_states: jax.Array, *, interpret: bool | None = None) -> j
 def price_pairs(a: jax.Array, b: jax.Array) -> jax.Array:
     """Best-available per-pair pricing: popcount(a[t] ^ b[t]) -> int32[T].
 
-    a, b: uint8[T, W, C] packed planes.  Dispatches to the compiled Pallas
+    a, b: uint8[T, W, C] packed planes (or the same sections flattened to
+    uint8[T, W*C] rows).  Dispatches to the compiled Pallas
     kernel on TPU and to the portable ``lax.population_count`` oracle on every
     other backend.  Safe to call inside jit; T may be 0.
     """

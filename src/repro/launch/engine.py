@@ -62,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.kernels._util import on_tpu
 from repro.launch import paged_cache, steps
 from repro.launch.paged_cache import PagedCacheConfig, PagedKVCache
 from repro.models import api
@@ -334,9 +335,11 @@ class Engine:
         # axis, the paged KV pools partition on the head axis (one shared
         # slot schedule / block table), and every dispatch runs the same
         # step functions SPMD (vmap-emulated on one device, or shard_map
-        # over ``tp_devices`` when a real N-device group is supplied).  The
-        # host scheduler below is untouched: wrapped steps return tokens /
-        # keys reduced to shard 0 (they are replicated across shards).
+        # over ``tp_devices`` when a real N-device group is supplied; on
+        # TPU a real group is required, so shards never silently share a
+        # chip).  The host scheduler below is untouched: wrapped steps
+        # return tokens / keys reduced to shard 0 (they are replicated
+        # across shards).
         if tp_devices is not None and tp == 1:
             tp = len(tp_devices)
         if tp > 1:
@@ -345,8 +348,14 @@ class Engine:
             # shard layout must not change across epochs
             self._tp = tp_mod.plan_tp(cfg, tp, packed=True)
             devs = tuple(tp_devices) if tp_devices is not None else None
-            if devs is not None and len(set(devs)) != tp:
-                devs = None  # repeated devices = 1-device emulation -> vmap
+            if devs is None or len(set(devs)) != tp:
+                if on_tpu():
+                    raise ValueError(
+                        f"tp={tp} on TPU needs {tp} distinct tp_devices, got "
+                        f"{tp_devices!r}: the vmap emulation would run every "
+                        "shard on one chip"
+                    )
+                devs = None  # missing/repeated devices = 1-device emulation -> vmap
             self._tp_devices = devs
             self.cfg_local = tp_mod.local_config(cfg, self._tp)
         else:
@@ -383,6 +392,8 @@ class Engine:
             self.pools = jax.tree.map(
                 lambda x: jnp.zeros((self._tp.n, *x.shape), x.dtype), self.pools
             )
+            if self._tp_devices is not None:
+                self.pools = tp_mod.place_shards(self.pools, self._tp, self._tp_devices)
 
         # two compiled quantum lengths: the full quantum for steady decoding
         # and a short one for when most live rows sit near retirement —
@@ -413,7 +424,7 @@ class Engine:
         else:
             donate = steps.cache_donation()
             self._decode_loops = {
-                q: jax.jit(
+                q: steps.serving_jit(
                     self._tp_wrap(
                         steps.make_paged_decode_loop(self.cfg_local, q, ecfg.page_size),
                         (True, True, False, False, False), (False, True, False),
@@ -422,7 +433,7 @@ class Engine:
                 )
                 for q in self._quanta
             }
-            self._prefill_step = jax.jit(
+            self._prefill_step = steps.serving_jit(
                 self._tp_wrap(
                     steps.make_prefill_chunk_step(self.cfg_local, ecfg.page_size),
                     (True, True, False, False, False, False), (False, False, True),
@@ -430,7 +441,7 @@ class Engine:
                 donate_argnums=donate,
             )
             self._fused_steps = {
-                q: jax.jit(
+                q: steps.serving_jit(
                     self._tp_wrap(
                         steps.make_fused_step(self.cfg_local, q, ecfg.page_size),
                         (True, True) + (False,) * 8, (False, False, False, True),
@@ -479,7 +490,10 @@ class Engine:
         """Serving-ready tree: prepared solo, or sharded+stacked under TP."""
         if self._tp is None:
             return steps.prepare_serving_params(params)
-        return tp_mod.prepare_tp_params(params, self._tp)
+        tree = tp_mod.prepare_tp_params(params, self._tp)
+        if self._tp_devices is not None:
+            tree = tp_mod.place_shards(tree, self._tp, self._tp_devices)
+        return tree
 
     def _tp_wrap(self, fn, stacked_in, stacked_out):
         """SPMD-wrap a step under TP (identity when unsharded)."""

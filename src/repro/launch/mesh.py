@@ -11,6 +11,8 @@ import warnings
 
 import jax
 
+from repro.kernels._util import on_tpu
+
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16x16 single-pod (256 chips) or 2x16x16 two-pod (512 chips) mesh.
@@ -54,10 +56,10 @@ def replica_submeshes(
     * ``shards_per_replica == 1`` — the PR 8 behavior: with more replicas
       than devices the assignment wraps silently (replicas share a device;
       how single-CPU tests run an N-replica fleet).
-    * ``shards_per_replica > 1`` and one physical device — every replica
-      gets the single device repeated (pure emulation: the TP layer runs
-      its shards under ``vmap`` on that device), with a warning so a
-      misconfigured production launch is loud.
+    * ``shards_per_replica > 1`` and one physical device — on CPU every
+      replica gets the single device repeated (pure emulation: the TP layer
+      runs its shards under ``vmap`` on that device), with a warning; on
+      TPU this is an error, since the shards would all share one chip.
     * ``shards_per_replica > 1`` on a real mesh — a replica whose group
       would straddle the device-list end non-contiguously (wrap-around
       mixing the first and last devices of the "model" axis) is REJECTED:
@@ -74,6 +76,11 @@ def replica_submeshes(
     if shards_per_replica == 1:
         return [[devs[i % d]] for i in range(n_replicas)]
     if d == 1:
+        if on_tpu():
+            raise ValueError(
+                f"{shards_per_replica}-way tensor parallelism needs "
+                f"{shards_per_replica} TPU devices, found 1"
+            )
         warnings.warn(
             f"{shards_per_replica}-way tensor parallelism on a single device: "
             "shards will be vmap-emulated, not distributed",

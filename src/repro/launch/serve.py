@@ -66,6 +66,7 @@ from repro.launch.steps import (
     make_prefill_step,
     make_serve_step,
     prepare_serving_params,
+    serving_jit,
 )
 from repro.models import api
 
@@ -89,14 +90,14 @@ def make_generator(
     # once-per-deployment packed->dense decompression on non-TPU backends;
     # every dispatch below (warmup included) reuses the prepared tree
     params = prepare_serving_params(params)
-    prefill = jax.jit(make_prefill_step(cfg))
+    prefill = serving_jit(make_prefill_step(cfg))
     donate = cache_donation()
     if loop == "scan":
-        decode = jax.jit(
+        decode = serving_jit(
             make_decode_loop(cfg, gen_len - 1, greedy=greedy), donate_argnums=donate
         )
     else:
-        serve = jax.jit(make_serve_step(cfg), donate_argnums=donate)
+        serve = serving_jit(make_serve_step(cfg), donate_argnums=donate)
 
     # cache sized for the full generation; encdec keeps a src-len cross cache
     cache = api.init_cache(

@@ -106,6 +106,21 @@ def make_serve_step(cfg: ArchConfig):
     return serve_step
 
 
+def serving_jit(fn, **kwargs):
+    """``jax.jit`` for serving dispatches: bfloat16 is rounded wherever the
+    program says so.
+
+    By default XLA may keep a bfloat16 intermediate in float32 inside a
+    fusion ("excess precision"), and which intermediates it keeps depends
+    on fusion and tiling decisions that change with the dispatch shape.  On
+    TPU that made one prompt's logits depend on its batch and made engine
+    streams diverge from solo ``serve.generate`` of the same request
+    (different dispatch shapes); with every rounding fixed by the program,
+    results no longer depend on the shape of the batch around a row.
+    """
+    return jax.jit(fn, compiler_options={"xla_allow_excess_precision": False}, **kwargs)
+
+
 def cache_donation() -> tuple[int, ...]:
     """``donate_argnums`` for the cache operand of serve_step / decode_loop.
 

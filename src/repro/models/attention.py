@@ -240,23 +240,16 @@ def decode_attention(
     of valid cache positions (the new token's KV must already be written) —
     or a (B,) vector of per-row lengths (ragged continuous-batching decode:
     every slot sits at its own position in its own sequence).
+
+    Runs the fixed-size key blocks of :func:`blockwise_attention`, so the
+    result does not depend on how long the cache view is: blocks past
+    ``valid_len`` add exact zeros, and a one-shot softmax over the whole
+    view would sum its terms in an order that changes with the view length
+    (on TPU, the engine's bucketed page views and a solo request's cache
+    then gave different tokens).
     """
-    b, hq, _, d = q.shape
-    _, hkv, s, _ = k_cache.shape
-    g = hq // hkv
-    qg = q.reshape(b, hkv, g, 1, d)
-    scale = d**-0.5
-    scores = jnp.einsum(
-        "bhgqd,bhkd->bhgqk", qg.astype(jnp.float32), k_cache.astype(jnp.float32)
-    ) * scale
-    pos = jnp.arange(s)
-    # scalar valid_len -> (1, S) mask shared by the batch (bit-identical to
-    # the historical path); vector -> (B, S) per-slot mask
-    vl = jnp.reshape(jnp.asarray(valid_len), (-1, 1))
-    mask = pos[None, :] < vl
-    if window is not None:
-        mask = jnp.logical_and(mask, pos[None, :] >= vl - window)
-    scores = jnp.where(mask[:, None, None, None, :], scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhgqk,bhkd->bhgqd", p, v_cache.astype(jnp.float32))
-    return out.reshape(b, hq, 1, d).astype(q.dtype)
+    vl = jnp.asarray(valid_len)
+    return blockwise_attention(
+        q, k_cache, v_cache, kind="swa" if window is not None else "causal",
+        window=window, q_offset=vl - 1, kv_valid_len=vl,
+    )

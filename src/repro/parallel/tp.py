@@ -51,8 +51,7 @@ from typing import Any, Mapping, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.core import simulator
@@ -265,6 +264,17 @@ def prepare_tp_params(params: Any, plan: TPPlan, prepare=None) -> Any:
     return stack_shards([prepare(shard_params(params, plan, i)) for i in range(plan.n)])
 
 
+def place_shards(tree: Any, plan: TPPlan, devices) -> Any:
+    """Lay a stacked per-shard tree over ``devices``: shard i on device i.
+
+    The layout ``shard_map`` expects for its stacked inputs, so dispatches
+    find params and pools already in place instead of moving them from the
+    device the stacking ran on every call.
+    """
+    mesh = Mesh(np.asarray(devices), (plan.axis,))
+    return jax.device_put(tree, NamedSharding(mesh, P(plan.axis)))
+
+
 def tree_has_packed(params: Any) -> bool:
     """True if any leaf of ``params`` is a packed CIM operand dict."""
     found = False
@@ -311,7 +321,12 @@ def _spmd(fn, plan: TPPlan, stacked_in: Sequence[bool], devices=None):
         out = fn(*local)
         return jax.tree.map(lambda x: x[None], out)
 
-    return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(plan.axis))
+    # check_vma=False: the body is the unmodified single-shard step, whose
+    # scan carries and kernels carry no varying-axis annotations; its psums
+    # are the only cross-shard communication, placed explicitly
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=P(plan.axis), check_vma=False
+    )
 
 
 def tp_step(fn, plan: TPPlan, stacked_in: Sequence[bool], stacked_out: Sequence[bool], devices=None):
@@ -355,7 +370,9 @@ def make_tp_generator(
     """
     import time
 
-    from repro.launch.steps import cache_donation, make_decode_loop, make_prefill_step
+    from repro.launch.steps import (
+        cache_donation, make_decode_loop, make_prefill_step, serving_jit,
+    )
     from repro.models import api
 
     if plan is None:
@@ -366,8 +383,8 @@ def make_tp_generator(
     tp_params = prepare_tp_params(params, plan)
 
     b, prompt_len = batch["tokens"].shape
-    prefill = jax.jit(_spmd(make_prefill_step(cfg_l), plan, (True, False), devices))
-    decode = jax.jit(
+    prefill = serving_jit(_spmd(make_prefill_step(cfg_l), plan, (True, False), devices))
+    decode = serving_jit(
         _spmd(
             make_decode_loop(cfg_l, gen_len - 1, greedy=greedy),
             plan, (True, True, False, False, False), devices,
@@ -378,7 +395,7 @@ def make_tp_generator(
         lambda x: jnp.zeros((plan.n, *x.shape), x.dtype),
         api.init_cache(cfg_l, b, prompt_len + gen_len),
     )
-    merge = jax.jit(
+    merge = serving_jit(
         _spmd(lambda c, pc: api.merge_prefill_cache(cfg_l, c, pc), plan, (True, True), devices)
     )
     key = jax.random.PRNGKey(seed)
@@ -472,7 +489,7 @@ def build_sharded_deployment(params: Any, spec, config, n: int, *, pools=None):
         key, sub = jax.random.split(key)
         report, w_hat = analyze_tensor(w, spec, config, sub, name=name, pool=pools[i % n])
         reports[name] = report
-        deployed[name] = w_hat
+        deployed[name] = np.asarray(w_hat)  # host copy, as build_deployment keeps
         owner[name] = i % n
     plan = DeploymentPlan(spec=spec, config=config, reports=reports, deployed=deployed)
     return plan, pools, owner

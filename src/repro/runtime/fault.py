@@ -24,7 +24,7 @@ import time
 from typing import Any, Callable, Optional, Tuple, Type
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(frozen=True)
 class FaultPolicy:
     """Bounded-retry policy.  ``backoff_s`` is the exponential base between
     attempts; ``jitter`` spreads each sleep to ``backoff_s * 2**attempt *

@@ -425,7 +425,13 @@ def test_mid_repair_replica_routed_around(gemma):
     _analyze_tensor_pool(w, spec, PlannerConfig(p_stuck=1.0, crossbars=4),
                          jax.random.PRNGKey(1), pool, name="t0")
     rec = mgr.tensors["t0"]
-    for c in (0, 2):  # two hard faults; budget=1 defers the second repair
+    # two hard faults in section 0's two most-populated columns: each remap
+    # then costs writes, so budget=1 defers the second repair (a remap of
+    # an all-zero column is free and would not spend the budget)
+    pop = np.unpackbits(rec.expected[0], axis=0).sum(axis=0).astype(np.int64)
+    faulty = np.argsort(-pop, kind="stable")[:2]
+    assert pop[faulty].min() > 1
+    for c in faulty:
         rec.stuck1[0, 0, c] |= 0x80
         for arr in (rec.expected, rec.reference, rec.stored):
             arr[0, 0, c] &= 0x7F

@@ -169,7 +169,13 @@ def test_repair_budget_defers_and_prioritizes_significance():
     converges over subsequent rounds."""
     pool, mgr, _ = _setup(IntegrityConfig(spare_cols=4, repair_budget=1))
     rec = mgr.tensors["t0"]
-    for c in (0, 2):  # one low-order, one high-order hard fault, same tile
+    # one low-order, one high-order hard fault in the same tile, in section
+    # 0's two most-populated columns: a remap of an all-zero column writes
+    # nothing and would not spend the budget
+    pop = np.unpackbits(rec.expected[0], axis=0).sum(axis=0).astype(np.int64)
+    lo, hi = sorted(np.argsort(-pop, kind="stable")[:2])
+    assert pop[[lo, hi]].min() > 1
+    for c in (lo, hi):
         rec.stuck1[0, 0, c] |= 0x80
         for arr in (rec.expected, rec.reference, rec.stored):
             arr[0, 0, c] &= 0x7F
@@ -178,8 +184,8 @@ def test_repair_budget_defers_and_prioritizes_significance():
         rec.parity[0] = np.bitwise_xor.reduce(rec.expected[0], axis=1)
     rep1 = mgr.scrub_round()
     assert rep1.pending > 0 and mgr.pending_faults() > 0
-    assert rec.col_map[0, 2] >= SPEC.cols  # MSB-side fault repaired first
-    assert rec.col_map[0, 0] == 0  # LSB-side fault deferred past the budget
+    assert rec.col_map[0, hi] >= SPEC.cols  # MSB-side fault repaired first
+    assert rec.col_map[0, lo] == lo  # LSB-side fault deferred past the budget
     mgr.scrub_until_clean()
     assert mgr.pending_faults() == 0 and mgr.verify_all() and mgr.clean
 

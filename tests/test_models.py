@@ -124,3 +124,29 @@ def test_param_counts_active_vs_total():
     total = api.param_count(params)
     active = api.active_param_count(params, cfg)
     assert active < total  # MoE: most experts inactive per token
+
+
+def test_reference_matches_model_forward(key):
+    """The plain float32 reference (models/reference.py) and the model's own
+    forward agree on logits for the dense GQA decoder at a small size."""
+    from repro.models import reference
+
+    cfg = get_arch("internlm2-1.8b", reduced=True)  # float32 compute
+    params = api.init(key, cfg)
+    batch = api.make_batch(cfg, key, 2, 24)
+    with jax.default_matmul_precision("highest"):
+        want, _ = api.forward(params, cfg, batch)
+    got = reference.logits(params, cfg, batch["tokens"])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    # bfloat16 matmul inputs move the logits, but only by rounding
+    low = reference.logits(params, cfg, batch["tokens"], dtype=jnp.bfloat16)
+    rel = float(jnp.linalg.norm(low - want) / jnp.linalg.norm(want))
+    assert 0 < rel < 5e-2
+
+
+def test_reference_rejects_other_architectures():
+    from repro.models import reference
+
+    cfg = get_arch("qwen2-moe-a2.7b", reduced=True)
+    with pytest.raises(NotImplementedError):
+        reference.logits({}, cfg, np.zeros((1, 4), np.int32))

@@ -23,8 +23,9 @@ The battery pins the TP contract end to end:
     across per-shard pools between engine dispatches without stalling the
     replica, and post-refresh tokens match the clean deployment;
 (6) *mesh carve-up* — ``replica_submeshes`` groups are contiguous on the
-    model axis, warn-and-emulate on one device, and reject non-contiguous
-    wrap-around.
+    model axis, warn-and-emulate on one CPU device, and reject
+    non-contiguous wrap-around; on TPU, a replica's shards must sit on
+    distinct chips (``replica_submeshes`` and ``Engine(tp=...)`` raise).
 
 The native ``shard_map`` path (real N-device mesh) is pinned by a
 subprocess test under ``--xla_force_host_platform_device_count=4`` (marked
@@ -142,6 +143,27 @@ def test_replica_submeshes_validation():
         replica_submeshes(0, 1)
     with pytest.raises(ValueError):
         replica_submeshes(1, 0)
+
+
+def test_replica_submeshes_single_tpu_device_raises(monkeypatch):
+    """On a TPU, shards never fall back to sharing one chip."""
+    import repro.launch.mesh as mesh_mod
+
+    monkeypatch.setattr(mesh_mod, "on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="needs 4 TPU devices"):
+        replica_submeshes(2, 4)
+
+
+@pytest.mark.parametrize("devices", ["missing", "repeated"])
+def test_engine_tp_on_tpu_requires_distinct_devices(lm, monkeypatch, devices):
+    """Engine(tp>1) on TPU raises where the CPU route would vmap-emulate."""
+    import repro.launch.engine as engine_mod
+
+    cfg, params = lm
+    monkeypatch.setattr(engine_mod, "on_tpu", lambda: True)
+    devs = None if devices == "missing" else [jax.devices()[0]] * 2
+    with pytest.raises(ValueError, match="2 distinct tp_devices"):
+        Engine(cfg, params, ECFG, tp=2, tp_devices=devs)
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,96 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e.
+
+Interpret mode runs these kernels on the CPU but never asks Mosaic (the
+TPU kernel compiler) whether it accepts them: a block layout it cannot
+lower (the Hamming kernel's old 1-D reduction output) or a tile that does
+not fit VMEM passes every interpret-mode test and fails on the chip.  The
+TPU compiler is installed here and compiles for a chip that is described
+rather than attached, so these tests compile each kernel at the widths the
+full-size deployment uses (internlm2-1.8b: d_model 2048, KV width 1024,
+d_ff 8192, vocab 92544; 128x10 crossbar sections) and check that the
+compiled program calls the kernel.  Nothing runs: this says nothing about
+results or times.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every test worker
+imports every test file.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.cim_matmul import ops as cim_ops
+from repro.kernels.hamming import ops as hamming_ops
+
+COLS = 10
+# (K, N) of every packed linear of internlm2-1.8b: q/o, k/v, gate/up, down, head
+SHAPES = [(2048, 2048), (2048, 1024), (2048, 8192), (8192, 2048), (2048, 92544)]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _compile(kernel: str, fn, *args) -> None:
+    """Compile ``fn`` and check that it calls the Pallas kernel ``kernel``."""
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [
+        line for line in hlo.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    names = [line.strip().removeprefix("ROOT ").split(" = ", 1)[0] for line in calls]
+    assert any(name.startswith(f"%{kernel}") for name in names), (kernel, names)
+
+
+@pytest.mark.parametrize("k,n", SHAPES)
+@pytest.mark.parametrize("m", [8, 256])
+def test_cim_matmul_packed_compiles_for_v5e(one_chip, m, k, n):
+    """Decode (M=8) and prefill-chunk (M=256) rows against packed planes."""
+    _compile(
+        "cim_matmul_packed_kernel",
+        lambda x, p, s: cim_ops.cim_matmul_packed(x, p, s, 0.5, interpret=False),
+        _spec(one_chip, (m, k), jnp.bfloat16),
+        _spec(one_chip, (COLS, k // 8, n), jnp.uint8),
+        _spec(one_chip, (k // 8, n), jnp.uint8),
+    )
+
+
+def test_cim_matmul_packed_skip_compiles_for_v5e(one_chip):
+    """The zero-tile skip twin (const_rle operands) at the MLP down shape."""
+    m, (k, n) = 256, SHAPES[3]
+    _compile(
+        "cim_matmul_packed_skip_kernel",
+        lambda x, p, s, nz: cim_ops.cim_matmul_packed(
+            x, p, s, 0.5, interpret=False, tile_nz=nz
+        ),
+        _spec(one_chip, (m, k), jnp.bfloat16),
+        _spec(one_chip, (COLS, k // 8, n), jnp.uint8),
+        _spec(one_chip, (k // 8, n), jnp.uint8),
+        _spec(one_chip, (COLS, k // 8 // 16), jnp.uint8),
+    )
+
+
+def test_hamming_pairs_compiles_for_v5e(one_chip):
+    """The planner's pricing kernel on 4096 pairs of 128x10 sections."""
+    a = _spec(one_chip, (4096, 16, COLS), jnp.uint8)
+    _compile(
+        "hamming_pairs_kernel",
+        lambda x, y: hamming_ops.hamming_pairs(x, y, interpret=False), a, a,
+    )
