@@ -1,0 +1,25 @@
+"""Whole serving step: model operations of every request the run served
+(its prompt's prefill and each token decoded after the first,
+``bench/roofline/dense_gqa.py``) over the summed host spans of the
+dispatching steps that did the work -- the window's and the drain's --
+against the chip's bfloat16 peak.  Time the engine spends waiting for
+arrivals is left out, so a faster step reads higher whatever the offered
+load; it bounds every kernel's share of the step."""
+from bench import common
+
+ops = common.load_module("roofline", "dense_gqa")
+
+
+def read(ctx):
+    win, model = ctx["window"], ctx["model"]
+    busy = sum(b - a for a, b in win["steps"])
+    total = 0.0
+    for r in win["records"]:
+        if not r["tokens"]:
+            continue
+        p = r["prompt"].size
+        total += ops.prefill_ops(model, p)
+        total += sum(ops.decode_ops(model, p + j) for j in range(1, len(r["tokens"])))
+    if total <= 0 or busy <= 0:
+        return None
+    return 100.0 * total / busy / ctx["peaks"]["bf16_flops_per_s"]
