@@ -231,22 +231,29 @@ def _prep_core_pool(
     s = total // spec.rows
     l = max(1, min(config.crossbars, s))
 
-    qt = bitslice.quantize(flat, spec.cols, spec.encoding)
-    q_padded = jnp.pad(qt.q, (0, pad))
-    sign_padded = jnp.pad(qt.sign, (0, pad), constant_values=1)
+    with jax.named_scope("plan.quantize"):
+        qt = bitslice.quantize(flat, spec.cols, spec.encoding)
+        q_padded = jnp.pad(qt.q, (0, pad))
+        sign_padded = jnp.pad(qt.sign, (0, pad), constant_values=1)
 
     chains = schedule.make_chains(s, l, config.schedule)
 
     # --- baseline: unsorted natural order, full reprogramming --------------
-    packed_u = bitslice.section_planes_packed(q_padded, spec.rows, spec.cols)
-    jobs_u = schedule.schedule_job_costs(packed_u, chains, include_initial=config.include_initial)
+    with jax.named_scope("plan.price_baseline"):
+        packed_u = bitslice.section_planes_packed(q_padded, spec.rows, spec.cols)
+        jobs_u = schedule.schedule_job_costs(
+            packed_u, chains, include_initial=config.include_initial
+        )
 
     # --- SWS order ---------------------------------------------------------
-    perm, inv_perm = _perm_full_with_inverse(flat_padded, spec, config, q_padded)
-    packed_s = bitslice.section_planes_packed(q_padded[perm], spec.rows, spec.cols)
+    with jax.named_scope("plan.sws_sort"):
+        perm, inv_perm = _perm_full_with_inverse(flat_padded, spec, config, q_padded)
+    with jax.named_scope("plan.pack"):
+        packed_s = bitslice.section_planes_packed(q_padded[perm], spec.rows, spec.cols)
+        sign_slots = sign_padded[perm].reshape(s, spec.rows)
     aux = {
         "packed_s": packed_s,
-        "sign_slots": sign_padded[perm].reshape(s, spec.rows),
+        "sign_slots": sign_slots,
         "scale": qt.scale,
         "offset": qt.offset,
         "inv_perm": inv_perm,
@@ -462,19 +469,20 @@ def _analyze_tensor_pool(
     l = max(1, min(config.crossbars, s))
     chains = schedule.make_chains(s, l, config.schedule)
 
-    if config.impl == "packed":
-        jobs_u, aux = _prep_core_pool(flat, spec, config)
-    elif config.impl == "bool":
-        qt, q_padded, sign_padded, chains, jobs_u, perm = _prep_bool(flat, spec, config)
-        aux = {
-            "packed_s": bitslice.section_planes_packed(q_padded[perm], spec.rows, spec.cols),
-            "sign_slots": sign_padded[perm].reshape(s, spec.rows),
-            "scale": qt.scale,
-            "offset": qt.offset,
-            "inv_perm": sws.inverse_permutation(perm),
-        }
-    else:
-        raise ValueError(f"unknown planner impl: {config.impl!r}")
+    with jax.profiler.TraceAnnotation("plan.prep"):
+        if config.impl == "packed":
+            jobs_u, aux = _prep_core_pool(flat, spec, config)
+        elif config.impl == "bool":
+            qt, q_padded, sign_padded, chains, jobs_u, perm = _prep_bool(flat, spec, config)
+            aux = {
+                "packed_s": bitslice.section_planes_packed(q_padded[perm], spec.rows, spec.cols),
+                "sign_slots": sign_padded[perm].reshape(s, spec.rows),
+                "scale": qt.scale,
+                "offset": qt.offset,
+                "inv_perm": sws.inverse_permutation(perm),
+            }
+        else:
+            raise ValueError(f"unknown planner impl: {config.impl!r}")
 
     # codec layer: the pool programs/prices/wears the *stored* bits
     # (planes.PlaneSet.physical — permuted columns, reconstructed constants),
@@ -510,14 +518,15 @@ def _analyze_tensor_pool(
     # Under a codec the readback is in the stored layout: fault masks have
     # already applied to the physical bits, and logical planes are recovered
     # *after* the read (planes.logical_from_physical), mirroring hardware.
-    achieved_read = prep.achieved_read
-    if pset is not None:
-        achieved_read = planes.logical_from_physical(achieved_read, pset.col_order)
-    w_hat_slots = _dequant_slots(
-        achieved_read, aux["sign_slots"], aux["scale"], aux["offset"], rows=spec.rows
-    )
-    w_hat_flat = w_hat_slots.reshape(-1)[aux["inv_perm"]][:n]
-    w_hat = w_hat_flat.reshape(w.shape).astype(w.dtype)
+    with jax.profiler.TraceAnnotation("plan.dequant"):
+        achieved_read = prep.achieved_read
+        if pset is not None:
+            achieved_read = planes.logical_from_physical(achieved_read, pset.col_order)
+        w_hat_slots = _dequant_slots(
+            achieved_read, aux["sign_slots"], aux["scale"], aux["offset"], rows=spec.rows
+        )
+        w_hat_flat = w_hat_slots.reshape(-1)[aux["inv_perm"]][:n]
+        w_hat = w_hat_flat.reshape(w.shape).astype(w.dtype)
 
     if pool.integrity is not None:
         # the reconstruction closure core/integrity.py needs to dequantize
@@ -532,26 +541,27 @@ def _analyze_tensor_pool(
             "dtype": w.dtype,
         })
 
-    jobs_u_np = np.asarray(jobs_u)
-    report = TensorReport(
-        name=name,
-        shape=tuple(w.shape),
-        n_weights=n,
-        n_sections=s,
-        transitions_baseline=int(np.sum(jobs_u_np, dtype=np.int64)),
-        transitions_sws=prep.transitions_full,
-        transitions_final=prep.transitions_programmed,
-        lockstep_time_unsorted=int(
-            schedule.lockstep_time_host(jobs_u_np, config.threads, sort_jobs=False)
-        ),
-        lockstep_time_greedy=int(
-            schedule.lockstep_time_host(prep.job_costs, config.threads, sort_jobs=True)
-        ),
-        lockstep_time_ideal=float(prep.transitions_full) / config.threads,
-        quant_mse=float(jnp.mean((flat - w_hat_flat) ** 2)),
-        scale=float(aux["scale"]),
-        offset=float(aux["offset"]),
-    )
+    with jax.profiler.TraceAnnotation("plan.report"):
+        jobs_u_np = np.asarray(jobs_u)
+        report = TensorReport(
+            name=name,
+            shape=tuple(w.shape),
+            n_weights=n,
+            n_sections=s,
+            transitions_baseline=int(np.sum(jobs_u_np, dtype=np.int64)),
+            transitions_sws=prep.transitions_full,
+            transitions_final=prep.transitions_programmed,
+            lockstep_time_unsorted=int(
+                schedule.lockstep_time_host(jobs_u_np, config.threads, sort_jobs=False)
+            ),
+            lockstep_time_greedy=int(
+                schedule.lockstep_time_host(prep.job_costs, config.threads, sort_jobs=True)
+            ),
+            lockstep_time_ideal=float(prep.transitions_full) / config.threads,
+            quant_mse=float(jnp.mean((flat - w_hat_flat) ** 2)),
+            scale=float(aux["scale"]),
+            offset=float(aux["offset"]),
+        )
     return report, w_hat
 
 
@@ -675,12 +685,16 @@ def _compile_prep_sizes(params: Any, spec: CrossbarSpec, config: PlannerConfig) 
         return
 
     def compile_one(n: int) -> None:
-        _prep_core_pool.lower(jax.ShapeDtypeStruct((n,), jnp.float32), spec, config).compile()
+        with jax.profiler.TraceAnnotation("plan.compile_prep.size"):
+            _prep_core_pool.lower(
+                jax.ShapeDtypeStruct((n,), jnp.float32), spec, config
+            ).compile()
 
     from concurrent.futures import ThreadPoolExecutor
 
-    with ThreadPoolExecutor(len(sizes)) as ex:
-        list(ex.map(compile_one, sizes))
+    with jax.profiler.TraceAnnotation("plan.compile_prep", sizes=len(sizes)):
+        with ThreadPoolExecutor(len(sizes)) as ex:
+            list(ex.map(compile_one, sizes))
 
 
 def build_deployment(
@@ -700,26 +714,35 @@ def build_deployment(
     deployment.  The per-tensor PRNG split discipline is identical with and
     without a pool, so resetting the pool between tensors recovers the
     stateless plan bit-exactly.
+
+    Profiler spans (``plan.*``, ``pool.*``; idle unless a trace is on) mark
+    the pass, the prep compiles, and each tensor's phases.
     """
-    if pool is not None and config.impl == "packed":
-        _compile_prep_sizes(params, spec, config)
-    key = jax.random.PRNGKey(config.seed)
-    reports: dict[str, TensorReport] = {}
-    deployed: dict[str, np.ndarray] = {}
-    for name, w in iter_weights(params, config):
-        key, sub = jax.random.split(key)
-        if progress:
-            progress(name)
-        report, w_hat = analyze_tensor(w, spec, config, sub, name=name, pool=pool)
-        reports[name] = report
-        deployed[name] = np.asarray(w_hat)
-    return DeploymentPlan(
-        spec=spec,
-        config=config,
-        reports=reports,
-        deployed=deployed,
-        pool_stats=pool.stats().to_dict() if pool is not None else None,
-    )
+    with jax.profiler.TraceAnnotation("plan.deployment"):
+        if pool is not None and config.impl == "packed":
+            _compile_prep_sizes(params, spec, config)
+        key = jax.random.PRNGKey(config.seed)
+        reports: dict[str, TensorReport] = {}
+        deployed: dict[str, np.ndarray] = {}
+        for name, w in iter_weights(params, config):
+            key, sub = jax.random.split(key)
+            if progress:
+                progress(name)
+            n = int(np.prod(w.shape))
+            with jax.profiler.TraceAnnotation(
+                "plan.tensor", n_weights=n, sections=-(-n // spec.rows)
+            ):
+                report, w_hat = analyze_tensor(w, spec, config, sub, name=name, pool=pool)
+                reports[name] = report
+                with jax.profiler.TraceAnnotation("plan.deployed.readback"):
+                    deployed[name] = np.asarray(w_hat)
+        return DeploymentPlan(
+            spec=spec,
+            config=config,
+            reports=reports,
+            deployed=deployed,
+            pool_stats=pool.stats().to_dict() if pool is not None else None,
+        )
 
 
 MATERIALIZATIONS = ("dense", "packed", "planes_int8")

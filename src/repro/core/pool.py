@@ -160,6 +160,7 @@ def _full_program_packed(
 
 
 @partial(jax.jit, static_argnames=("rows", "stuck_cols"))
+@jax.named_scope("pool.stuck_walk")
 def _stuck_program_packed(
     packed: jax.Array, padded: jax.Array, valid: jax.Array, keys: jax.Array,
     state_assigned: jax.Array, p: jax.Array | float, *, rows: int, stuck_cols: int,
@@ -421,138 +422,143 @@ class CrossbarPool:
         (``include_initial`` semantics are inherently True for a pool: the
         seam is a physical write).
         """
-        if impl not in ("packed", "bool"):
-            raise ValueError(f"unknown pool impl: {impl!r}")
-        leveling = self.leveling if leveling is None else leveling
-        if leveling not in LEVELINGS:
-            raise ValueError(f"unknown pool leveling {leveling!r}; choose from {LEVELINGS}")
-        col_order = None
-        if hasattr(packed, "physical"):  # PlaneSet: program the stored bits
-            if getattr(packed, "col_order", None) is not None:
-                col_order = np.asarray(packed.col_order)
-            packed = packed.physical()
-        packed = jnp.asarray(packed)
-        if packed.dtype != jnp.uint8:
-            packed = bitslice.pack_rows(packed)
-        s, words, cols = packed.shape
-        if (words, cols) != (self._words, self.spec.cols):
-            raise ValueError(
-                f"section planes {packed.shape} do not fit pool geometry "
-                f"{self.spec.rows}x{self.spec.cols}"
-            )
-        chains = [np.asarray(c, dtype=np.int32) for c in chains]
-        lc = len(chains)
-        if not 1 <= lc <= self.n_crossbars:
-            raise ValueError(f"{lc} chains for a pool of {self.n_crossbars} crossbars")
-        if key is None:
-            key = jax.random.PRNGKey(0)
-        rows = self.spec.rows
-        full = p_stuck >= 1.0 or stuck_cols == 0
-
-        planes = bitslice.unpack_rows(packed, rows) if impl == "bool" else None
-
-        # --- intra-chain job costs (assignment-independent) ----------------
-        prev_i, cur_i = schedule.chain_pairs(chains, include_initial=False)
-        if impl == "packed":
-            intra = np.asarray(
-                _price_intra_packed(packed, prev_i, cur_i), np.int64
-            ) if prev_i.size else np.zeros((0,), np.int64)
-        else:
-            intra = (
-                np.asarray(cost.pair_transitions(planes[prev_i], planes[cur_i]), np.int64)
-                if prev_i.size else np.zeros((0,), np.int64)
-            )
-        lens = [len(c) - 1 for c in chains]
-        intra_per_chain = np.split(intra, np.cumsum(lens)[:-1]) if lc else []
-        chain_intra = np.array([x.sum() for x in intra_per_chain], np.int64)
-
-        # --- chain -> crossbar assignment + seam pricing --------------------
-        assignment = self._assign(chain_intra, leveling, packed=packed, chains=chains)
-        firsts = np.array([c[0] for c in chains], np.int32)
-        assignment_dev = jnp.asarray(assignment)
-        state_assigned = self._state[assignment_dev]
-        if impl == "packed":
-            seam = np.asarray(
-                hamming_ops.price_pairs(state_assigned, packed[firsts]), np.int64
-            )
-        else:
-            state_bool = np.asarray(bitslice.unpack_rows(self._state, rows))[assignment]
-            seam = np.asarray(
-                cost.pair_transitions(jnp.asarray(state_bool), planes[firsts]), np.int64
-            )
-        job_costs = np.concatenate(
-            [np.concatenate([seam[j : j + 1], intra_per_chain[j]]) for j in range(lc)]
-        )
-        chain_totals = seam + chain_intra
-
-        # --- the physical walk: wear, final states, achieved planes ---------
-        padded, valid, keys = _pad_chains(chains, key)
-        if impl == "packed":
-            if full:
-                wear_inc, new_states = _full_program_packed(
-                    state_assigned, packed, padded, valid, rows=rows
+        with jax.profiler.TraceAnnotation("pool.program") as span:
+            if impl not in ("packed", "bool"):
+                raise ValueError(f"unknown pool impl: {impl!r}")
+            leveling = self.leveling if leveling is None else leveling
+            if leveling not in LEVELINGS:
+                raise ValueError(f"unknown pool leveling {leveling!r}; choose from {LEVELINGS}")
+            col_order = None
+            if hasattr(packed, "physical"):  # PlaneSet: program the stored bits
+                if getattr(packed, "col_order", None) is not None:
+                    col_order = np.asarray(packed.col_order)
+                packed = packed.physical()
+            packed = jnp.asarray(packed)
+            if packed.dtype != jnp.uint8:
+                packed = bitslice.pack_rows(packed)
+            s, words, cols = packed.shape
+            if (words, cols) != (self._words, self.spec.cols):
+                raise ValueError(
+                    f"section planes {packed.shape} do not fit pool geometry "
+                    f"{self.spec.rows}x{self.spec.cols}"
                 )
-                achieved = packed
-                programmed_job_costs = job_costs
+            chains = [np.asarray(c, dtype=np.int32) for c in chains]
+            lc = len(chains)
+            if not 1 <= lc <= self.n_crossbars:
+                raise ValueError(f"{lc} chains for a pool of {self.n_crossbars} crossbars")
+            if key is None:
+                key = jax.random.PRNGKey(0)
+            rows = self.spec.rows
+            full = p_stuck >= 1.0 or stuck_cols == 0
+
+            span.set_metadata(chains=lc)
+
+            planes = bitslice.unpack_rows(packed, rows) if impl == "bool" else None
+
+            # --- intra-chain job costs (assignment-independent) ----------------
+            prev_i, cur_i = schedule.chain_pairs(chains, include_initial=False)
+            with jax.profiler.TraceAnnotation("pool.price_intra"):
+                if not prev_i.size:
+                    intra = np.zeros((0,), np.int64)
+                elif impl == "packed":
+                    intra = _price_intra_packed(packed, prev_i, cur_i)
+                else:
+                    intra = cost.pair_transitions(planes[prev_i], planes[cur_i])
+            with jax.profiler.TraceAnnotation("pool.price_intra.readback"):
+                intra = np.asarray(intra, np.int64)
+            lens = [len(c) - 1 for c in chains]
+            intra_per_chain = np.split(intra, np.cumsum(lens)[:-1]) if lc else []
+            chain_intra = np.array([x.sum() for x in intra_per_chain], np.int64)
+
+            # --- chain -> crossbar assignment + seam pricing --------------------
+            with jax.profiler.TraceAnnotation("pool.assign"):
+                assignment = self._assign(chain_intra, leveling, packed=packed, chains=chains)
+                firsts = np.array([c[0] for c in chains], np.int32)
+                assignment_dev = jnp.asarray(assignment)
+                state_assigned = self._state[assignment_dev]
+            with jax.profiler.TraceAnnotation("pool.seam"):
+                if impl == "packed":
+                    seam = hamming_ops.price_pairs(state_assigned, packed[firsts])
+                else:
+                    state_bool = np.asarray(bitslice.unpack_rows(self._state, rows))[assignment]
+                    seam = cost.pair_transitions(jnp.asarray(state_bool), planes[firsts])
+            with jax.profiler.TraceAnnotation("pool.seam.readback"):
+                seam = np.asarray(seam, np.int64)
+            job_costs = np.concatenate(
+                [np.concatenate([seam[j : j + 1], intra_per_chain[j]]) for j in range(lc)]
+            )
+            chain_totals = seam + chain_intra
+
+            # --- the physical walk: wear, final states, achieved planes ---------
+            with jax.profiler.TraceAnnotation("pool.walk"):
+                padded, valid, keys = _pad_chains(chains, key)
+                counts, programmed_job_costs = None, job_costs
+                if impl == "bool":
+                    counts_b, wear_inc, finals_b, achieved_b = _program_bool_reference(
+                        np.asarray(planes), state_bool, chains, p_stuck, key,
+                        stuck_cols=stuck_cols,
+                    )
+                    programmed_job_costs = np.array(
+                        [c for per_chain in counts_b for c in per_chain], np.int64
+                    )
+                    new_states = bitslice.pack_rows(jnp.asarray(finals_b))
+                    achieved = bitslice.pack_rows(jnp.asarray(achieved_b))
+                elif full:
+                    wear_inc, new_states = _full_program_packed(
+                        state_assigned, packed, padded, valid, rows=rows
+                    )
+                    achieved = packed
+                else:
+                    counts, wear_inc, new_states, achieved = _stuck_program_packed(
+                        packed, padded, valid, keys, state_assigned, p_stuck,
+                        rows=rows, stuck_cols=stuck_cols,
+                    )
+            with jax.profiler.TraceAnnotation("pool.walk.readback"):
+                if counts is not None:
+                    counts = np.asarray(counts, np.int64)
+                    programmed_job_costs = np.concatenate(
+                        [counts[j, : len(c)] for j, c in enumerate(chains)]
+                    )
+                wear_inc = np.asarray(wear_inc, np.int64)
+
+            # --- non-ideal readback ---------------------------------------------
+            if self.faults is None:
+                achieved_read = achieved
             else:
-                counts, wear_inc, new_states, achieved = _stuck_program_packed(
-                    packed, padded, valid, keys, state_assigned, p_stuck,
-                    rows=rows, stuck_cols=stuck_cols,
+                from repro.core import nonideal
+
+                sec_xbar = np.zeros(s, np.int32)
+                for j, c in enumerate(chains):
+                    sec_xbar[c] = assignment[j]
+                idx = jnp.asarray(sec_xbar)
+                achieved_read = nonideal.read_packed(
+                    achieved, self.faults.stuck0[idx], self.faults.stuck1[idx]
                 )
-                counts = np.asarray(counts, np.int64)
-                programmed_job_costs = np.concatenate(
-                    [counts[j, : len(c)] for j, c in enumerate(chains)]
+
+            # --- commit ---------------------------------------------------------
+            with jax.profiler.TraceAnnotation("pool.commit"):
+                self._state = self._state.at[assignment_dev].set(new_states)
+                self.wear[assignment] += wear_inc
+                self.tensors_seen += 1
+                self.programs += int(job_costs.shape[0])
+                wear_total = int(wear_inc.sum())
+                self.total_writes += wear_total
+
+                report = PoolProgramReport(
+                    name=name,
+                    assignment=assignment,
+                    seam_costs=seam,
+                    chain_totals=chain_totals,
+                    job_costs=job_costs,
+                    programmed_job_costs=programmed_job_costs,
+                    transitions_full=int(job_costs.sum()),
+                    transitions_programmed=int(programmed_job_costs.sum()),
+                    wear_increment_total=wear_total,
+                    wear_increment_max=int(wear_inc.max()),
+                    achieved=achieved,
+                    achieved_read=achieved_read,
                 )
-            wear_inc = np.asarray(wear_inc, np.int64)
-            new_states = jnp.asarray(new_states)
-        else:
-            counts_b, wear_inc, finals_b, achieved_b = _program_bool_reference(
-                np.asarray(planes), state_bool, chains, p_stuck, key,
-                stuck_cols=stuck_cols,
-            )
-            programmed_job_costs = np.array(
-                [c for per_chain in counts_b for c in per_chain], np.int64
-            )
-            new_states = bitslice.pack_rows(jnp.asarray(finals_b))
-            achieved = bitslice.pack_rows(jnp.asarray(achieved_b))
-
-        # --- non-ideal readback ---------------------------------------------
-        if self.faults is None:
-            achieved_read = achieved
-        else:
-            from repro.core import nonideal
-
-            sec_xbar = np.zeros(s, np.int32)
-            for j, c in enumerate(chains):
-                sec_xbar[c] = assignment[j]
-            idx = jnp.asarray(sec_xbar)
-            achieved_read = nonideal.read_packed(
-                achieved, self.faults.stuck0[idx], self.faults.stuck1[idx]
-            )
-
-        # --- commit ---------------------------------------------------------
-        self._state = self._state.at[assignment_dev].set(new_states)
-        self.wear[assignment] += wear_inc
-        self.tensors_seen += 1
-        self.programs += int(job_costs.shape[0])
-        wear_total = int(wear_inc.sum())
-        self.total_writes += wear_total
-
-        report = PoolProgramReport(
-            name=name,
-            assignment=assignment,
-            seam_costs=seam,
-            chain_totals=chain_totals,
-            job_costs=job_costs,
-            programmed_job_costs=programmed_job_costs,
-            transitions_full=int(job_costs.sum()),
-            transitions_programmed=int(programmed_job_costs.sum()),
-            wear_increment_total=wear_total,
-            wear_increment_max=int(wear_inc.max()),
-            achieved=achieved,
-            achieved_read=achieved_read,
-        )
-        if self.integrity is not None:
-            # register reference planes + tile checksums for the scrub loop
-            self.integrity.register(report, chains=chains, col_order=col_order)
-        return report
+                if self.integrity is not None:
+                    # register reference planes + tile checksums for the scrub loop
+                    self.integrity.register(report, chains=chains, col_order=col_order)
+            return report

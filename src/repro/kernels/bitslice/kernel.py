@@ -54,4 +54,5 @@ def bitslice_kernel(
         out_specs=pl.BlockSpec((cols, bk, bn), lambda i, j: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct((cols, k, n), jnp.int8),
         interpret=interpret,
+        name="bitslice_kernel",
     )(inv_scale.reshape(1, 1).astype(jnp.float32), w)

@@ -107,6 +107,7 @@ def cim_matmul_kernel(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="cim_matmul_kernel",
     )(x, splanes)
 
 
@@ -235,6 +236,7 @@ def cim_matmul_packed_skip_kernel(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="cim_matmul_packed_skip_kernel",
     )(tile_nz.astype(jnp.int32), x, planes_packed, sign_packed)
 
 
@@ -278,4 +280,5 @@ def cim_matmul_packed_kernel(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
         interpret=interpret,
+        name="cim_matmul_packed_kernel",
     )(x, planes_packed, sign_packed)

@@ -169,4 +169,5 @@ def flash_attention_kernel(
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_kernel",
     )(q, k, v, qoff, kvl)

@@ -65,4 +65,5 @@ def hamming_pairs_kernel(
         out_specs=pl.BlockSpec((bt,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((t,), jnp.int32),
         interpret=interpret,
+        name="hamming_pairs_kernel",
     )(a.reshape(t, wc), b.reshape(t, wc))

@@ -702,20 +702,30 @@ class Engine:
         After a hot swap the occupied slots may span several param epochs;
         each epoch gets its own dispatch round (same compiled variants —
         only the traced param argument differs), normally exactly one
-        extra round for the handful of cycles the old epoch drains."""
-        self._expire(now)
-        self._admit(now)
-        epochs = sorted({s.epoch for s in self.slots if s is not None})
-        did = False
-        for ep in epochs:
-            if self.ecfg.fused:
-                did = self._fused_round(now, ep) or did
-            else:
-                did = self._prefill_round(now, ep) or did
-                did = self._decode(now, ep) or did
-        self._scrub_tick()
-        self._gc_params()
-        return did
+        extra round for the handful of cycles the old epoch drains.
+
+        Profiler spans (``engine.*``, idle unless a trace is on) mark the
+        cycle's parts: admission, and per dispatch its host-array build
+        (``prepare``), the jitted call (``dispatch``), the blocking token
+        read (``readback``) and the token bookkeeping (``commit``)."""
+        with jax.profiler.TraceAnnotation("engine.step") as span:
+            span.set_metadata(
+                waiting=len(self.waiting),
+                live=sum(s is not None for s in self.slots),
+            )
+            self._expire(now)
+            self._admit(now)
+            epochs = sorted({s.epoch for s in self.slots if s is not None})
+            did = False
+            for ep in epochs:
+                if self.ecfg.fused:
+                    did = self._fused_round(now, ep) or did
+                else:
+                    did = self._prefill_round(now, ep) or did
+                    did = self._decode(now, ep) or did
+            self._scrub_tick()
+            self._gc_params()
+            return did
 
     def run(self, requests: list[Request]) -> list[RequestResult]:
         """Serve ``requests`` to completion (wall-clock arrival times).
@@ -885,21 +895,25 @@ class Engine:
         lazy); preempted requests restore their snapshot (swap) or start a
         teacher-forced replay (recompute).  Admission itself never preempts
         — new arrivals are the lowest-priority work in the system."""
-        for i, slot in enumerate(self.slots):
-            if slot is not None or not self.waiting:
-                continue
-            head = self.waiting[0]
-            if head.arrival_time > now:
-                break  # FIFO: later arrivals wait behind the head
-            if isinstance(head, ResumeState):
-                if not self._readmit(i, head):
-                    break  # out of blocks until a retirement frees some
-            else:
-                first = min(self.ecfg.prefill_chunk, head.prompt.size)
-                if not self.kv.ensure_capacity(i, first):
-                    break
-                self.slots[i] = _Slot(head, now, epoch=self.params_epoch)
-            self.waiting.popleft()
+        with jax.profiler.TraceAnnotation("engine.admit") as span:
+            admitted = 0
+            for i, slot in enumerate(self.slots):
+                if slot is not None or not self.waiting:
+                    continue
+                head = self.waiting[0]
+                if head.arrival_time > now:
+                    break  # FIFO: later arrivals wait behind the head
+                if isinstance(head, ResumeState):
+                    if not self._readmit(i, head):
+                        break  # out of blocks until a retirement frees some
+                else:
+                    first = min(self.ecfg.prefill_chunk, head.prompt.size)
+                    if not self.kv.ensure_capacity(i, first):
+                        break
+                    self.slots[i] = _Slot(head, now, epoch=self.params_epoch)
+                self.waiting.popleft()
+                admitted += 1
+            span.set_metadata(admitted=admitted)
 
     def _readmit(self, idx: int, rec: ResumeState) -> bool:
         """Seat a preempted request back into slot ``idx``; False if the
@@ -1105,150 +1119,160 @@ class Engine:
         Degenerate mixes route to the dedicated dispatches: all-decode uses
         the pure decode loop (no dead chunk stage), all-mid-prompt the pure
         chunk step (no dead scan)."""
-        occupied = [
-            i for i, s in enumerate(self.slots)
-            if s is not None and s.epoch == epoch
-        ]
-        if not occupied:
-            return False
+        with jax.profiler.TraceAnnotation("engine.fused") as span:
+            occupied = [
+                i for i, s in enumerate(self.slots)
+                if s is not None and s.epoch == epoch
+            ]
+            if not occupied:
+                return False
 
-        def c_true(s: _Slot) -> int:
-            return min(self.ecfg.prefill_chunk, s.target.size - s.prefill_done)
+            def c_true(s: _Slot) -> int:
+                return min(self.ecfg.prefill_chunk, s.target.size - s.prefill_done)
 
-        def finishing(s: _Slot) -> bool:
-            return s.prefill_done + c_true(s) == s.target.size
+            def finishing(s: _Slot) -> bool:
+                return s.prefill_done + c_true(s) == s.target.size
 
-        dec = [i for i in occupied if self.slots[i].state == _DECODE]
-        pf = [i for i in occupied if self.slots[i].state == _PREFILL]
-        if not pf:
-            return self._decode(now, epoch)
-        active0 = dec + [i for i in pf if finishing(self.slots[i])]
-        if not active0:
-            return self._prefill_round(now, epoch)
-        # lone-prefill batching (same lever as the split path's deferral): a
-        # single fresh admission still pays a whole chunk stage; with more
-        # requests queued, waiting one cycle lets the next retirement's
-        # admission share it, halving the chunk-stage bill when short
-        # requests churn through the slots
-        if (
-            len(pf) == 1
-            and self.waiting
-            and not self.slots[pf[0]].pf_deferred
-            and len(dec) >= max(2, self.ecfg.max_slots // 2)
-        ):
-            self.slots[pf[0]].pf_deferred = True
-            return self._decode(now, epoch)
+            dec = [i for i in occupied if self.slots[i].state == _DECODE]
+            pf = [i for i in occupied if self.slots[i].state == _PREFILL]
+            if not pf:
+                return self._decode(now, epoch)
+            active0 = dec + [i for i in pf if finishing(self.slots[i])]
+            if not active0:
+                return self._prefill_round(now, epoch)
+            # lone-prefill batching (same lever as the split path's deferral): a
+            # single fresh admission still pays a whole chunk stage; with more
+            # requests queued, waiting one cycle lets the next retirement's
+            # admission share it, halving the chunk-stage bill when short
+            # requests churn through the slots
+            if (
+                len(pf) == 1
+                and self.waiting
+                and not self.slots[pf[0]].pf_deferred
+                and len(dec) >= max(2, self.ecfg.max_slots // 2)
+            ):
+                self.slots[pf[0]].pf_deferred = True
+                return self._decode(now, epoch)
 
-        # quantum from the decoding rows' remaining budgets
-        rem = [
-            self.slots[i].req.max_new_tokens - len(self.slots[i].generated)
-            for i in active0
-        ]
-        q = self._choose_quantum(rem)
+            # quantum from the decoding rows' remaining budgets
+            rem = [
+                self.slots[i].req.max_new_tokens - len(self.slots[i].generated)
+                for i in active0
+            ]
+            q = self._choose_quantum(rem)
 
-        def fused_need(s: _Slot) -> int:
-            cap = self._cap_tokens(s.req)
-            if s.state == _DECODE:
-                return min(s.pos + q, cap)
-            if finishing(s):
-                return min(s.target.size + q, cap)
-            return s.prefill_done + c_true(s)
+            def fused_need(s: _Slot) -> int:
+                cap = self._cap_tokens(s.req)
+                if s.state == _DECODE:
+                    return min(s.pos + q, cap)
+                if finishing(s):
+                    return min(s.target.size + q, cap)
+                return s.prefill_done + c_true(s)
 
-        rows = self._secure_rows(occupied, fused_need)
-        pf_rows = [i for i in rows if self.slots[i].state == _PREFILL]
-        scan_rows = [
-            i for i in rows
-            if self.slots[i].state == _DECODE or finishing(self.slots[i])
-        ]
-        if not pf_rows:
-            return self._decode(now, epoch) if scan_rows else False
-        if not scan_rows:
-            return self._prefill_round(now, epoch)
+            rows = self._secure_rows(occupied, fused_need)
+            pf_rows = [i for i in rows if self.slots[i].state == _PREFILL]
+            scan_rows = [
+                i for i in rows
+                if self.slots[i].state == _DECODE or finishing(self.slots[i])
+            ]
+            if not pf_rows:
+                return self._decode(now, epoch) if scan_rows else False
+            if not scan_rows:
+                return self._prefill_round(now, epoch)
 
-        page = self.ecfg.page_size
-        c = _bucket(max(c_true(self.slots[i]) for i in pf_rows), self.ecfg.prefill_chunk)
-        bp = _bucket(len(pf_rows), self.ecfg.max_slots)
-        nb = _bucket(len(scan_rows), self.ecfg.max_slots)
+            page = self.ecfg.page_size
+            c = _bucket(max(c_true(self.slots[i]) for i in pf_rows), self.ecfg.prefill_chunk)
+            bp = _bucket(len(pf_rows), self.ecfg.max_slots)
+            nb = _bucket(len(scan_rows), self.ecfg.max_slots)
 
-        def scan_pos0(s: _Slot) -> int:
-            return s.pos if s.state == _DECODE else s.target.size
+            def scan_pos0(s: _Slot) -> int:
+                return s.pos if s.state == _DECODE else s.target.size
 
-        pages = _bucket(
-            max(
-                max(-(-(self.slots[i].prefill_done + c) // page) for i in pf_rows),
-                max(-(-(scan_pos0(self.slots[i]) + q) // page) for i in scan_rows),
-            ),
-            self.pcfg.max_pages,
-        )
-        self._shapes_seen.add(("fused", q, c, bp, nb, pages))
+            pages = _bucket(
+                max(
+                    max(-(-(self.slots[i].prefill_done + c) // page) for i in pf_rows),
+                    max(-(-(scan_pos0(self.slots[i]) + q) // page) for i in scan_rows),
+                ),
+                self.pcfg.max_pages,
+            )
+            self._shapes_seen.add(("fused", q, c, bp, nb, pages))
+            span.set_metadata(
+                rows=len(scan_rows), rows_padded=nb - len(scan_rows), pages=pages, q=q,
+                prefill_rows=len(pf_rows), prefill_rows_padded=bp - len(pf_rows),
+                tokens=sum(c_true(self.slots[i]) for i in pf_rows),
+            )
 
-        pf_tokens = np.zeros((bp, c), np.int32)
-        pf_table = np.zeros((bp, pages), np.int32)
-        pf_meta = np.zeros((bp, 5), np.int32)
-        pf_meta[:, 1] = 1  # pad rows: kv_len 1 (any valid value)
-        pf_keys = np.zeros((bp, 2), np.uint32)
-        for m, i in enumerate(pf_rows):
-            s = self.slots[i]
-            ct = c_true(s)
-            start = s.prefill_done
-            pf_tokens[m, :ct] = s.target[start : start + ct]
-            pf_table[m] = self.kv.table_rows([i], pages)[0]
-            pf_keys[m] = s.key
-            consume = finishing(s) and s.replay is None  # replays never re-sample
-            pf_meta[m] = (start, start + ct, ct - 1, int(s.req.greedy), int(consume))
+            with jax.profiler.TraceAnnotation("engine.fused.prepare"):
+                pf_tokens = np.zeros((bp, c), np.int32)
+                pf_table = np.zeros((bp, pages), np.int32)
+                pf_meta = np.zeros((bp, 5), np.int32)
+                pf_meta[:, 1] = 1  # pad rows: kv_len 1 (any valid value)
+                pf_keys = np.zeros((bp, 2), np.uint32)
+                for m, i in enumerate(pf_rows):
+                    s = self.slots[i]
+                    ct = c_true(s)
+                    start = s.prefill_done
+                    pf_tokens[m, :ct] = s.target[start : start + ct]
+                    pf_table[m] = self.kv.table_rows([i], pages)[0]
+                    pf_keys[m] = s.key
+                    consume = finishing(s) and s.replay is None  # replays never re-sample
+                    pf_meta[m] = (start, start + ct, ct - 1, int(s.req.greedy), int(consume))
 
-        table = np.zeros((nb, pages), np.int32)
-        state = np.zeros((nb, 5), np.int32)
-        state[:, 2] = 1  # pad rows: greedy (no PRNG consumption)
-        keys = np.zeros((nb, 2), np.uint32)
-        join = np.full((nb,), -1, np.int32)
-        for r, i in enumerate(scan_rows):
-            s = self.slots[i]
-            table[r] = self.kv.table_rows([i], pages)[0]
-            keys[r] = s.key
-            if s.state == _DECODE:
-                state[r] = (s.tok_next, s.pos, int(s.req.greedy), 0, 0)
-            else:
-                replay = s.replay is not None
-                join[r] = pf_rows.index(i)
-                state[r] = (
-                    0, s.target.size, int(s.req.greedy),
-                    s.saved_tok if replay else 0, int(replay),
+                table = np.zeros((nb, pages), np.int32)
+                state = np.zeros((nb, 5), np.int32)
+                state[:, 2] = 1  # pad rows: greedy (no PRNG consumption)
+                keys = np.zeros((nb, 2), np.uint32)
+                join = np.full((nb,), -1, np.int32)
+                for r, i in enumerate(scan_rows):
+                    s = self.slots[i]
+                    table[r] = self.kv.table_rows([i], pages)[0]
+                    keys[r] = s.key
+                    if s.state == _DECODE:
+                        state[r] = (s.tok_next, s.pos, int(s.req.greedy), 0, 0)
+                    else:
+                        replay = s.replay is not None
+                        join[r] = pf_rows.index(i)
+                        state[r] = (
+                            0, s.target.size, int(s.req.greedy),
+                            s.saved_tok if replay else 0, int(replay),
+                        )
+
+            with jax.profiler.TraceAnnotation("engine.fused.dispatch"):
+                pf_tok, toks, keys_out, self.pools = self._fused_steps[q](
+                    self._params[epoch], self.pools, pf_table, pf_tokens, pf_meta,
+                    pf_keys, table, state, keys, join,
                 )
+            with jax.profiler.TraceAnnotation("engine.fused.readback"):
+                pf_tok = np.asarray(pf_tok)
+                toks = np.asarray(toks)
+                keys_out = np.asarray(keys_out)
+            with jax.profiler.TraceAnnotation("engine.fused.commit"):
+                self.stats["fused_dispatches"] += 1
+                self.stats["decode_rows_live"] += len(
+                    [i for i in scan_rows if self.slots[i].state == _DECODE]
+                )
+                self.stats["decode_rows_padded"] += nb - len(scan_rows)
 
-        pf_tok, toks, keys_out, self.pools = self._fused_steps[q](
-            self._params[epoch], self.pools, pf_table, pf_tokens, pf_meta,
-            pf_keys, table, state, keys, join,
-        )
-        pf_tok = np.asarray(pf_tok)
-        toks = np.asarray(toks)
-        keys_out = np.asarray(keys_out)
-        self.stats["fused_dispatches"] += 1
-        self.stats["decode_rows_live"] += len(
-            [i for i in scan_rows if self.slots[i].state == _DECODE]
-        )
-        self.stats["decode_rows_padded"] += nb - len(scan_rows)
-
-        for m, i in enumerate(pf_rows):
-            self.slots[i].prefill_done += c_true(self.slots[i])
-        for r, i in enumerate(scan_rows):
-            s = self.slots[i]
-            s.key = keys_out[r]
-            if s.state == _DECODE:
-                self._consume_quantum(i, toks[r, :q], s.pos + q, now)
-                continue
-            end_pos = s.target.size + q
-            s.state = _DECODE
-            if s.replay is not None:
-                s.replay = None  # the first token was emitted pre-preemption
-                self._consume_quantum(i, toks[r, :q], end_pos, now)
-                continue
-            s.t_first_token = now
-            if self._append_token(i, int(pf_tok[join[r]]), now):
-                self.stats["tokens_overrun"] += q  # retired on its 1st token
-                continue
-            self._consume_quantum(i, toks[r, :q], end_pos, now)
-        return True
+                for m, i in enumerate(pf_rows):
+                    self.slots[i].prefill_done += c_true(self.slots[i])
+                for r, i in enumerate(scan_rows):
+                    s = self.slots[i]
+                    s.key = keys_out[r]
+                    if s.state == _DECODE:
+                        self._consume_quantum(i, toks[r, :q], s.pos + q, now)
+                        continue
+                    end_pos = s.target.size + q
+                    s.state = _DECODE
+                    if s.replay is not None:
+                        s.replay = None  # the first token was emitted pre-preemption
+                        self._consume_quantum(i, toks[r, :q], end_pos, now)
+                        continue
+                    s.t_first_token = now
+                    if self._append_token(i, int(pf_tok[join[r]]), now):
+                        self.stats["tokens_overrun"] += q  # retired on its 1st token
+                        continue
+                    self._consume_quantum(i, toks[r, :q], end_pos, now)
+            return True
 
     def _consume_quantum(
         self, idx: int, emitted: np.ndarray, end_pos: int, now: float
@@ -1274,151 +1298,164 @@ class Engine:
         row's final chunk also samples its first token in-graph (adopted
         unless the row is a recompute replay, whose first token was emitted
         before its preemption)."""
-        rows = [
-            i for i, s in enumerate(self.slots)
-            if s is not None and s.state == _PREFILL and s.epoch == epoch
-        ]
-        if not rows:
-            return False
-        # lone-prefill batching: with decode busy and more requests queued, a
-        # single fresh admission waits one cycle so the next retirement's
-        # admission can share its dispatch (single-row prefills dominate the
-        # prefill bill in steady state otherwise).  Only relevant in split
-        # mode — the fused path batches a lone prefill with decode anyway.
-        if (
-            not self.ecfg.fused
-            and len(rows) == 1
-            and self.waiting
-            and not self.slots[rows[0]].pf_deferred
-            and sum(
-                1 for s in self.slots if s is not None and s.state == _DECODE
-            ) >= max(2, self.ecfg.max_slots // 2)
-        ):
-            self.slots[rows[0]].pf_deferred = True
-            return False
-        c = self.ecfg.prefill_chunk
-        page = self.ecfg.page_size
+        with jax.profiler.TraceAnnotation("engine.prefill") as span:
+            rows = [
+                i for i, s in enumerate(self.slots)
+                if s is not None and s.state == _PREFILL and s.epoch == epoch
+            ]
+            if not rows:
+                return False
+            # lone-prefill batching: with decode busy and more requests queued, a
+            # single fresh admission waits one cycle so the next retirement's
+            # admission can share its dispatch (single-row prefills dominate the
+            # prefill bill in steady state otherwise).  Only relevant in split
+            # mode — the fused path batches a lone prefill with decode anyway.
+            if (
+                not self.ecfg.fused
+                and len(rows) == 1
+                and self.waiting
+                and not self.slots[rows[0]].pf_deferred
+                and sum(
+                    1 for s in self.slots if s is not None and s.state == _DECODE
+                ) >= max(2, self.ecfg.max_slots // 2)
+            ):
+                self.slots[rows[0]].pf_deferred = True
+                return False
+            c = self.ecfg.prefill_chunk
+            page = self.ecfg.page_size
 
-        rows = self._secure_rows(
-            rows,
-            lambda s: s.prefill_done + min(c, s.target.size - s.prefill_done),
-        )
-        if not rows:
-            return False
-        c_trues = [
-            min(c, self.slots[i].target.size - self.slots[i].prefill_done)
-            for i in rows
-        ]
-        nb = _bucket(len(rows), self.ecfg.max_slots)
-        # the view must address the full PADDED chunk width [start, start+c):
-        # pad-column write-backs beyond a slot's allocation land in the dummy
-        # page via its dummy table entries, never clamp onto real cells
-        pages = _bucket(
-            max(-(-(self.slots[i].prefill_done + c) // page) for i in rows),
-            self.pcfg.max_pages,
-        )
-        self._shapes_seen.add(("prefill", nb, pages))
+            rows = self._secure_rows(
+                rows,
+                lambda s: s.prefill_done + min(c, s.target.size - s.prefill_done),
+            )
+            if not rows:
+                return False
+            c_trues = [
+                min(c, self.slots[i].target.size - self.slots[i].prefill_done)
+                for i in rows
+            ]
+            nb = _bucket(len(rows), self.ecfg.max_slots)
+            # the view must address the full PADDED chunk width [start, start+c):
+            # pad-column write-backs beyond a slot's allocation land in the dummy
+            # page via its dummy table entries, never clamp onto real cells
+            pages = _bucket(
+                max(-(-(self.slots[i].prefill_done + c) // page) for i in rows),
+                self.pcfg.max_pages,
+            )
+            self._shapes_seen.add(("prefill", nb, pages))
+            span.set_metadata(rows=len(rows), rows_padded=nb - len(rows), pages=pages,
+                              tokens=sum(c_trues))
 
-        tokens = np.zeros((nb, c), np.int32)
-        table = np.zeros((nb, pages), np.int32)
-        meta = np.zeros((nb, 4), np.int32)
-        meta[:, 1] = 1  # pad rows: kv_len 1 (any valid value)
-        keys = np.zeros((nb, 2), np.uint32)
-        for r, (i, ct) in enumerate(zip(rows, c_trues)):
-            slot = self.slots[i]
-            start = slot.prefill_done
-            tokens[r, :ct] = slot.target[start : start + ct]
-            table[r] = self.kv.table_rows([i], pages)[0]
-            meta[r] = (start, start + ct, ct - 1, int(slot.req.greedy))
-            keys[r] = slot.key
+            with jax.profiler.TraceAnnotation("engine.prefill.prepare"):
+                tokens = np.zeros((nb, c), np.int32)
+                table = np.zeros((nb, pages), np.int32)
+                meta = np.zeros((nb, 4), np.int32)
+                meta[:, 1] = 1  # pad rows: kv_len 1 (any valid value)
+                keys = np.zeros((nb, 2), np.uint32)
+                for r, (i, ct) in enumerate(zip(rows, c_trues)):
+                    slot = self.slots[i]
+                    start = slot.prefill_done
+                    tokens[r, :ct] = slot.target[start : start + ct]
+                    table[r] = self.kv.table_rows([i], pages)[0]
+                    meta[r] = (start, start + ct, ct - 1, int(slot.req.greedy))
+                    keys[r] = slot.key
 
-        toks, keys_out, self.pools = self._prefill_step(
-            self._params[epoch], self.pools, table, tokens, meta, keys
-        )
-        self.stats["prefill_dispatches"] += 1
-        done_rows = [
-            (r, i) for r, (i, ct) in enumerate(zip(rows, c_trues))
-            if self.slots[i].prefill_done + ct == self.slots[i].target.size
-        ]
-        toks_h = np.asarray(toks) if done_rows else None
-        keys_h = np.asarray(keys_out) if done_rows else None
-        for r, (i, ct) in enumerate(zip(rows, c_trues)):
-            slot = self.slots[i]
-            slot.prefill_done += ct
-            if slot.prefill_done < slot.target.size:
-                continue  # mid-prompt chunk: discard tok, keep the unsplit key
-            if slot.replay is not None:
-                # recompute replay complete: resume decode with the token
-                # emitted before preemption — never re-sample it
-                slot.pos = slot.replay.size
-                slot.tok_next = slot.saved_tok
-                slot.replay = None
-                slot.state = _DECODE
-                continue
-            # prompt complete: the dispatch sampled the first token in-graph
-            # with the same pick path + PRNG schedule as serve.generate
-            slot.key = keys_h[r]
-            slot.state = _DECODE
-            slot.pos = slot.req.prompt.size
-            slot.tok_next = int(toks_h[r])
-            slot.t_first_token = now
-            self._append_token(i, slot.tok_next, now)
-        return True
+            with jax.profiler.TraceAnnotation("engine.prefill.dispatch"):
+                toks, keys_out, self.pools = self._prefill_step(
+                    self._params[epoch], self.pools, table, tokens, meta, keys
+                )
+            self.stats["prefill_dispatches"] += 1
+            done_rows = [
+                (r, i) for r, (i, ct) in enumerate(zip(rows, c_trues))
+                if self.slots[i].prefill_done + ct == self.slots[i].target.size
+            ]
+            with jax.profiler.TraceAnnotation("engine.prefill.readback"):
+                toks_h = np.asarray(toks) if done_rows else None
+                keys_h = np.asarray(keys_out) if done_rows else None
+            with jax.profiler.TraceAnnotation("engine.prefill.commit"):
+                for r, (i, ct) in enumerate(zip(rows, c_trues)):
+                    slot = self.slots[i]
+                    slot.prefill_done += ct
+                    if slot.prefill_done < slot.target.size:
+                        continue  # mid-prompt chunk: discard tok, keep the unsplit key
+                    if slot.replay is not None:
+                        # recompute replay complete: resume decode with the token
+                        # emitted before preemption — never re-sample it
+                        slot.pos = slot.replay.size
+                        slot.tok_next = slot.saved_tok
+                        slot.replay = None
+                        slot.state = _DECODE
+                        continue
+                    # prompt complete: the dispatch sampled the first token in-graph
+                    # with the same pick path + PRNG schedule as serve.generate
+                    slot.key = keys_h[r]
+                    slot.state = _DECODE
+                    slot.pos = slot.req.prompt.size
+                    slot.tok_next = int(toks_h[r])
+                    slot.t_first_token = now
+                    self._append_token(i, slot.tok_next, now)
+            return True
 
     # -- split decode -------------------------------------------------------
 
     def _decode(self, now: float, epoch: int = 0) -> bool:
         """One decode-quantum dispatch over every decoding slot of ``epoch``
         (the pure path — also the fused round's degenerate all-decode case)."""
-        rows = [
-            i for i, s in enumerate(self.slots)
-            if s is not None and s.state == _DECODE and s.epoch == epoch
-        ]
-        if not rows:
-            return False
-        rem = [
-            self.slots[i].req.max_new_tokens - len(self.slots[i].generated)
-            for i in rows
-        ]
-        q = self._choose_quantum(rem)
+        with jax.profiler.TraceAnnotation("engine.decode") as span:
+            rows = [
+                i for i, s in enumerate(self.slots)
+                if s is not None and s.state == _DECODE and s.epoch == epoch
+            ]
+            if not rows:
+                return False
+            rem = [
+                self.slots[i].req.max_new_tokens - len(self.slots[i].generated)
+                for i in rows
+            ]
+            q = self._choose_quantum(rem)
 
-        rows = self._secure_rows(
-            rows, lambda s: min(s.pos + q, self._cap_tokens(s.req))
-        )
-        if not rows:
-            return False
+            rows = self._secure_rows(
+                rows, lambda s: min(s.pos + q, self._cap_tokens(s.req))
+            )
+            if not rows:
+                return False
 
-        page = self.ecfg.page_size
-        nb = _bucket(len(rows), self.ecfg.max_slots)
-        pages = _bucket(
-            max(-(-(self.slots[i].pos + q) // page) for i in rows), self.pcfg.max_pages
-        )
-        self._shapes_seen.add(("decode", q, nb, pages))
+            page = self.ecfg.page_size
+            nb = _bucket(len(rows), self.ecfg.max_slots)
+            pages = _bucket(
+                max(-(-(self.slots[i].pos + q) // page) for i in rows), self.pcfg.max_pages
+            )
+            self._shapes_seen.add(("decode", q, nb, pages))
+            span.set_metadata(rows=len(rows), rows_padded=nb - len(rows), pages=pages, q=q)
 
-        table = np.zeros((nb, pages), np.int32)  # pad rows -> dummy page
-        table[: len(rows)] = self.kv.table_rows(rows, pages)
-        state = np.zeros((nb, 3), np.int32)  # [tok, pos, greedy] per row
-        state[:, 2] = 1
-        keys = np.zeros((nb, 2), np.uint32)
-        for r, i in enumerate(rows):
-            s = self.slots[i]
-            state[r] = (s.tok_next, s.pos, int(s.req.greedy))
-            keys[r] = s.key
+            with jax.profiler.TraceAnnotation("engine.decode.prepare"):
+                table = np.zeros((nb, pages), np.int32)  # pad rows -> dummy page
+                table[: len(rows)] = self.kv.table_rows(rows, pages)
+                state = np.zeros((nb, 3), np.int32)  # [tok, pos, greedy] per row
+                state[:, 2] = 1
+                keys = np.zeros((nb, 2), np.uint32)
+                for r, i in enumerate(rows):
+                    s = self.slots[i]
+                    state[r] = (s.tok_next, s.pos, int(s.req.greedy))
+                    keys[r] = s.key
 
-        toks, self.pools, keys_out = self._decode_loops[q](
-            self._params[epoch], self.pools, table, state, keys
-        )
-        toks = np.asarray(toks)
-        keys_out = np.asarray(keys_out)
-        self.stats["decode_dispatches"] += 1
-        self.stats["decode_rows_live"] += len(rows)
-        self.stats["decode_rows_padded"] += nb - len(rows)
+            with jax.profiler.TraceAnnotation("engine.decode.dispatch"):
+                toks, self.pools, keys_out = self._decode_loops[q](
+                    self._params[epoch], self.pools, table, state, keys
+                )
+            with jax.profiler.TraceAnnotation("engine.decode.readback"):
+                toks = np.asarray(toks)
+                keys_out = np.asarray(keys_out)
+            with jax.profiler.TraceAnnotation("engine.decode.commit"):
+                self.stats["decode_dispatches"] += 1
+                self.stats["decode_rows_live"] += len(rows)
+                self.stats["decode_rows_padded"] += nb - len(rows)
 
-        for r, i in enumerate(rows):
-            slot = self.slots[i]
-            slot.key = keys_out[r]
-            self._consume_quantum(i, toks[r, :q], slot.pos + q, now)
-        return True
+                for r, i in enumerate(rows):
+                    slot = self.slots[i]
+                    slot.key = keys_out[r]
+                    self._consume_quantum(i, toks[r, :q], slot.pos + q, now)
+            return True
 
 
 # ---------------------------------------------------------------------------

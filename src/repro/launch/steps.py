@@ -166,6 +166,7 @@ def make_decode_loop(cfg: ArchConfig, n_steps: int, *, greedy: bool = True):
     return decode_loop
 
 
+@jax.named_scope("sample")
 def _row_pick(logits, keys, greedy, consume=None):
     """Per-row token pick — THE sampling path and PRNG split schedule shared
     by every ragged dispatch (decode loop, prefill chunk, fused step), so

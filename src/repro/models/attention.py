@@ -74,6 +74,7 @@ def _block_mask(
 
 
 @functools.partial(jax.jit, static_argnames=("window", "q_offset", "block_q"))
+@jax.named_scope("attention")
 def banded_swa_attention(
     q: jax.Array,
     k: jax.Array,
@@ -144,6 +145,7 @@ def banded_swa_attention(
 @functools.partial(
     jax.jit, static_argnames=("kind", "window", "block_k", "skip_masked_blocks")
 )
+@jax.named_scope("attention")
 def blockwise_attention(
     q: jax.Array,
     k: jax.Array,

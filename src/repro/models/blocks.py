@@ -155,6 +155,7 @@ def init_attn_pool(cfg: ArchConfig, num_tokens: int, dtype) -> dict[str, Any]:
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+@jax.named_scope("kv_gather")
 def gather_pool_view(pool_arr: jax.Array, table: jax.Array, page_size: int) -> jax.Array:
     """(..., T, Hkv, hd) pool + (B, P) block table -> (..., B, Hkv, L, hd)
     contiguous per-slot cache view, L = P * page_size."""
@@ -167,6 +168,7 @@ def gather_pool_view(pool_arr: jax.Array, table: jax.Array, page_size: int) -> j
     return jnp.moveaxis(view, -2, -3)
 
 
+@jax.named_scope("kv_scatter")
 def scatter_pool_view(
     pool_arr: jax.Array,
     view: jax.Array,
