@@ -15,7 +15,7 @@ turning the paper's endurance accounting into a live reliability policy:
   fault masks (``achieved_read`` — the deployment's bit-exact contract), and
   per-tile checksums over the expected read.  Tiles are
   ``IntegrityConfig.tile_bytes`` packed bytes (default 16 — one
-  ``planes.OPERAND_TILE_BYTES`` tile = one bk=128 kernel K-block), with a
+  ``planes.OPERAND_TILE_BYTES`` tile = 128 weight rows), with a
   position-weighted byte sum per (section, tile, column): any single-byte
   change is detected (weights 1..16 make byte deltas non-cancelling) and an
   optional spare parity column (XOR of all data columns) cross-checks

@@ -61,9 +61,9 @@ from repro.kernels.hamming import ops as hamming_ops
 
 CODECS = ("raw", "const_rle", "col_perm", "col_perm_rle")
 
-# serving-side zero-tile granularity: 16 packed bytes = 128 weight rows, the
-# packed kernel's K block (ops.cim_matmul_packed, bk=128), so one flag maps
-# to exactly one kernel tile
+# serving-side zero-tile granularity: 16 packed bytes = 128 weight rows; the
+# packed kernel's skip twin (ops.cim_matmul_packed) reads two flags per
+# 256-row decode chunk
 OPERAND_TILE_BYTES = 16
 
 

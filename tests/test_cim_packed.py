@@ -40,28 +40,59 @@ def _packed_operands(w, cols, encoding="sign_magnitude"):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize(
-    "m,k,n,cols",
+    "m,k,n,cols,x_kind",
     [
-        (4, 32, 16, 4),
-        (17, 100, 60, 8),   # K not divisible by 8
-        (128, 128, 128, 10),
-        (1, 7, 3, 10),      # degenerate decode shapes
-        (3, 9, 130, 6),
-        (8, 1, 1, 2),
-        (300, 40, 5, 10),   # M larger than one chunk-of-8
+        pytest.param(4, 32, 16, 4, "f32", id="4-32-16-4"),
+        pytest.param(17, 100, 60, 8, "f32", id="17-100-60-8"),  # K not divisible by 8
+        pytest.param(128, 128, 128, 10, "f32", id="128-128-128-10"),
+        pytest.param(1, 7, 3, 10, "f32", id="1-7-3-10"),  # degenerate decode shapes
+        pytest.param(3, 9, 130, 6, "f32", id="3-9-130-6"),
+        pytest.param(8, 1, 1, 2, "f32", id="8-1-1-2"),
+        pytest.param(300, 40, 5, 10, "f32", id="300-40-5-10"),  # M larger than one chunk-of-8
+        # several 2048 x 512 tiles along K and N, ragged tails on both
+        (8, 4046, 1466, 10, "f32"),
+        (8, 4046, 1466, 10, "bf16"),
+        (16, 4046, 1466, 8, "bf16"),  # cols 8: no second integer part
+        (5, 300, 200, 2, "bf16"),
+        (24, 2000, 700, 10, "int_bf16"),  # exact sums: bit-equal to the reference
     ],
 )
-def test_packed_kernel_vs_ref(m, k, n, cols):
+def test_packed_kernel_vs_ref(m, k, n, cols, x_kind):
     kx, kw = jax.random.split(jax.random.PRNGKey(m * 7 + n))
-    x = jax.random.normal(kx, (m, k))
+    if x_kind == "int_bf16":
+        x = jnp.round(jax.random.uniform(kx, (m, k), minval=-4, maxval=4)).astype(jnp.bfloat16)
+    else:
+        x = jax.random.normal(kx, (m, k)).astype(jnp.bfloat16 if x_kind == "bf16" else jnp.float32)
     w = jax.random.normal(kw, (k, n)) * 0.1
     pp, sp, qt = _packed_operands(w, cols)
     got = cm_ops.cim_matmul_packed(x, pp, sp, qt.scale, interpret=True)
     want = cm_ref.cim_matmul_packed(x, pp, sp, qt.scale)
+    if x_kind == "int_bf16":
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     # and against the dense quantized matmul (the end-to-end contract)
     w_hat = bitslice.dequantize(qt).reshape(w.shape)
-    np.testing.assert_allclose(got, x @ w_hat, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(got, x.astype(jnp.float32) @ w_hat, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_packed_skip_twin_matches_plain(dtype, key):
+    """The zero-chunk skip twin equals the plain kernel on the same operands,
+    bit for bit, with chunks of every kind: all planes zero, only the low
+    8 planes live, and both groups live."""
+    kx, kw = jax.random.split(key)
+    k, n, cols = 2048, 640, 10
+    w = jax.random.normal(kw, (k, n)) * 0.1
+    # rows 0-511 zero, rows 512-1023 small (planes 8-9 zero), the rest full range
+    w = w * jnp.where(jnp.arange(k) < 512, 0.0, jnp.where(jnp.arange(k) < 1024, 0.01, 1.0))[:, None]
+    pp, sp, qt = _packed_operands(w, cols)
+    t = np.asarray(pp).reshape(cols, k // 128, 16, n).any(axis=(2, 3)).astype(np.uint8)
+    live = np.asarray(cm_ops._live_groups(jnp.asarray(t), cols, k))
+    assert {0, 1, 2} <= set(live.tolist()), live
+    x = jax.random.normal(kx, (8, k)).astype(dtype)
+    with_skip = cm_ops.cim_matmul_packed(x, pp, sp, qt.scale, tile_nz=t, interpret=True)
+    without = cm_ops.cim_matmul_packed(x, pp, sp, qt.scale, interpret=True)
+    np.testing.assert_array_equal(np.asarray(with_skip), np.asarray(without))
 
 
 @pytest.mark.parametrize("mode", ["fused_dequant", "planes"])
@@ -119,7 +150,10 @@ def test_block_clamp_non_hardware_block_sizes(key):
     want = cm_ref.cim_matmul(x, sp8, qt.scale)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     pp, sp = bitslice.pack_linear_planes(q, 4), bitslice.pack_linear_sign(sign)
-    got_p = cm_ops.cim_matmul_packed(x, pp, sp, qt.scale, bn=100, bk=100, interpret=True)
+    # the packed kernel takes no block sizes: it sizes its tiles from the shapes
+    bk, bn = cm_ops.packed_tiles(24, 70, 33, 4, 4)
+    assert (bk % 256, bn % 128) == (0, 0)
+    got_p = cm_ops.cim_matmul_packed(x, pp, sp, qt.scale, interpret=True)
     np.testing.assert_allclose(got_p, want, rtol=1e-5, atol=1e-5)
 
 

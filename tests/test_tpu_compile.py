@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import functools
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -49,15 +50,17 @@ def _spec(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
-def _compile(kernel: str, fn, *args) -> None:
-    """Compile ``fn`` and check that it calls the Pallas kernel ``kernel``."""
+def _compile(kernel: str, fn, *args) -> list[str]:
+    """Compile ``fn``, check that it calls the Pallas kernel ``kernel`` and
+    return the HLO lines of those calls."""
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     calls = [
-        line for line in hlo.splitlines()
+        line.strip().removeprefix("ROOT ") for line in hlo.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line
     ]
-    names = [line.strip().removeprefix("ROOT ").split(" = ", 1)[0] for line in calls]
+    names = [line.split(" = ", 1)[0] for line in calls]
     assert any(name.startswith(f"%{kernel}") for name in names), (kernel, names)
+    return [line for line in calls if line.startswith(f"%{kernel}")]
 
 
 @pytest.mark.parametrize("k,n", SHAPES)
@@ -71,6 +74,47 @@ def test_cim_matmul_packed_compiles_for_v5e(one_chip, m, k, n):
         _spec(one_chip, (COLS, k // 8, n), jnp.uint8),
         _spec(one_chip, (k // 8, n), jnp.uint8),
     )
+
+
+@pytest.mark.parametrize("m,k,n,dtype", [
+    (8, 2048, 92544, jnp.float32),  # the LM head: float32 activations
+    (8, 8192, 2048, jnp.bfloat16),
+    (256, 2048, 8192, jnp.bfloat16),
+])
+def test_cim_matmul_packed_call_parses_for_roofline(one_chip, m, k, n, dtype):
+    """The compiled kernel call still shows ``x[M, K]``, ``u8[cols, K/8, N]``
+    and ``u8[K/8, N]``: the benchmark's roofline reader
+    (``bench/roofline/cim_matmul.parse_call``) reads the call's shapes from
+    them, and a call it cannot read silences the roofline share."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import common
+
+    roofline = common.load_module("roofline", "cim_matmul")
+    calls = _compile(
+        "cim_matmul_packed_kernel",
+        lambda x, p, s: cim_ops.cim_matmul_packed(x, p, s, 0.5, interpret=False),
+        _spec(one_chip, (m, k), dtype),
+        _spec(one_chip, (COLS, k // 8, n), jnp.uint8),
+        _spec(one_chip, (k // 8, n), jnp.uint8),
+    )
+    itemsize = jnp.dtype(dtype).itemsize
+    assert [roofline.parse_call(line) for line in calls] == [(m, k, n, COLS, itemsize)]
+
+
+def test_cim_matmul_packed_compiles_under_highest_precision(one_chip):
+    """A caller's ``default_matmul_precision("highest")`` reaches the kernel's
+    dots; the bfloat16 parts are exact, so their dots keep one pass (Mosaic
+    refuses bfloat16 operands at float32 precision)."""
+    with jax.default_matmul_precision("highest"):
+        _compile(
+            "cim_matmul_packed_kernel",
+            lambda x, p, s: cim_ops.cim_matmul_packed(x, p, s, 0.5, interpret=False),
+            _spec(one_chip, (8, 2048), jnp.bfloat16),
+            _spec(one_chip, (COLS, 256, 2048), jnp.uint8),
+            _spec(one_chip, (256, 2048), jnp.uint8),
+        )
 
 
 def test_cim_matmul_packed_skip_compiles_for_v5e(one_chip):
@@ -114,7 +158,7 @@ def _kernel_calls(one_chip):
         "cim_matmul_packed_skip_kernel": (
             cim_k.cim_matmul_packed_skip_kernel,
             (s((8, 2048), f32), s((COLS, 256, 1024), u8), s((256, 1024), u8),
-             s((COLS * 16,), u8))),
+             s((2048 // 256,), i32))),
         "cim_matmul_kernel": (
             cim_k.cim_matmul_kernel, (s((128, 256), f32), s((COLS, 256, 256), jnp.int8))),
         "hamming_pairs_kernel": (
