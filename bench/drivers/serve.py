@@ -1,9 +1,13 @@
 """Serving cells: an open-loop window through ``Engine.submit`` / ``Engine.step``.
 
-Set-up makes the configuration's weights on the device from the seed, on
-the crossbar grid (``bench/weights.py``), turns the planner-deployed ones
-into packed serving operands with ``core.simulator.operands_from_dense``
-(what ``deploy_params(materialize="packed")`` calls), builds the
+The configuration's ``"reference"`` names the module that owns its
+architecture (``bench/reference/<name>.py``: sizes, parameter leaves,
+reference weights and gaps; ``bench/reference/__init__.py``); nothing here
+knows one.  Set-up makes the configuration's weights on the device from
+the seed, on the crossbar grid (``bench/weights.py``) of the reference's
+leaves, turns the planner-deployed ones into packed serving operands with
+``core.simulator.operands_from_dense`` (what
+``deploy_params(materialize="packed")`` calls), builds the
 ``Engine`` and compiles and runs every dispatch shape the traffic can
 reach (``Engine.prewarm``'s grid, cut to the mix's lengths).  The
 window then offers the cell's traffic on its schedule: each request is
@@ -26,10 +30,10 @@ Host times the harness takes itself, after each ``Engine.step`` returns
   would report it.
 
 ``correct`` compares what the window served with the float32 reference
-(``bench/reference/dense_gqa.py``): a seeded sample of the finished
-requests, the longest among them, re-run over prompt + served tokens; the
-number compared is the widest gap by which a served token's reference
-logit lies below the reference's best at its position.
+the configuration names: a seeded sample of the finished requests, the
+longest among them, re-run over prompt + served tokens; the number
+compared is the widest gap by which a served token's reference logit lies
+below the reference's best at its position.
 """
 from __future__ import annotations
 
@@ -43,7 +47,6 @@ import numpy as np
 
 from bench import common, weights as W
 from bench import trace as T
-from bench.reference import dense_gqa as ref
 
 PLANNER_ENCODING = "sign_magnitude"
 CONTROL = "float8_e4m3fn"  # the type the control rounds matmul inputs to (bfloat16 configs)
@@ -72,24 +75,31 @@ def _nest(flat: dict) -> dict:
     return out
 
 
-def served_params(arch, model: dict, seed: int):
+def reference(conf: dict):
+    """The reference module the configuration names (``"reference"``)."""
+    return common.load_module("reference", conf["reference"])
+
+
+def served_params(arch, ref, model: dict, seed: int):
     """The served parameter tree, made on the device in one jitted call.
 
-    Planner-deployed matrices become packed operands (norm gains, which the
-    program serves dense, stay dense grid values); the rest are dense."""
+    The tree is the one ``ref.leaves(model)`` declares, and the program's
+    own tree has to match it leaf for leaf.  Planner-deployed matrices
+    become packed operands (norm gains, which the program serves dense,
+    stay dense grid values); the rest are dense."""
     from repro.core import simulator
     from repro.core.planner import MATERIALIZE_DENSE_ONLY, PlannerConfig, iter_weights
     from repro.models import api
 
-    lay = W.layout(model)
+    lay = W.layout(ref.leaves(model))
     shapes = _names(jax.eval_shape(functools.partial(api.init, cfg=arch), jax.random.PRNGKey(0)))
     if {n: tuple(s.shape) for n, s in shapes.items()} != {l["name"]: l["shape"] for l in lay}:
         raise ValueError(f"{arch.name}: the program's parameter tree is not the layout "
-                         "bench/weights.py draws")
+                         f"{ref.__name__} declares")
     deployed = {n for n, _ in iter_weights(shapes, PlannerConfig())}
     if deployed != {l["name"] for l in lay if l["grid"]}:
         raise ValueError(f"{arch.name}: the planner deploys {sorted(deployed)}, "
-                         "not the tensors bench/weights.py puts on the grid")
+                         f"not the tensors bench/weights.py puts on {ref.__name__}'s grid")
 
     def packed(leaf) -> bool:
         return leaf["grid"] and not set(leaf["name"].split("/")) & set(MATERIALIZE_DENSE_ONLY)
@@ -327,7 +337,8 @@ def sample(records: list[dict], seed: int, min_tokens: int, max_requests: int) -
     return out
 
 
-def check(model: dict, seed: int, picked: list[dict], pad_to: int, *, control=None) -> float:
+def check(ref, model: dict, seed: int, picked: list[dict], pad_to: int, *,
+          control=None) -> float:
     wts = ref.Weights(model, seed)
     gaps = ref.served_gaps(wts, [(r["prompt"], r["tokens"]) for r in picked], pad_to,
                            control=control)
@@ -345,14 +356,15 @@ def judge(cell: dict, gap: float, unfinished: int) -> tuple[bool, dict]:
 
 
 def control(ctx: dict, rounding) -> tuple[bool, dict]:
-    """The control, judged as a run is: the reference in the program's place
-    with every matmul input rounded to ``rounding``, over the run's own
-    sample (same prompts and served tokens); at each position the token the
-    control puts first.  It serves every request it is given."""
+    """The control, judged as a run is: the reference (``ctx["ref"]``) in
+    the program's place with every matmul input rounded to ``rounding``,
+    over the run's own sample (same prompts and served tokens); at each
+    position the token the control puts first.  It serves every request it
+    is given."""
     cell, seed = ctx["cell"], ctx["args"].seed
     picked = sample(ctx["window"]["records"], seed, cell["sample"]["min_tokens"],
                     cell["sample"]["max_requests"])
-    gap = check(ctx["model"], seed, picked, ctx["config"]["engine"]["max_seq_len"],
+    gap = check(ctx["ref"], ctx["model"], seed, picked, ctx["config"]["engine"]["max_seq_len"],
                 control=rounding)
     return judge(cell, gap, 0)
 
@@ -361,12 +373,12 @@ def control(ctx: dict, rounding) -> tuple[bool, dict]:
 # A run
 # ---------------------------------------------------------------------------
 
-def setup(arch, conf: dict, mix: dict, seed: int):
+def setup(arch, ref, conf: dict, mix: dict, seed: int):
     from repro.launch.engine import Engine, EngineConfig
 
-    model = common.model_dims(conf)
+    model = ref.dims(conf)
     t = time.perf_counter()
-    params = served_params(arch, model, seed)
+    params = served_params(arch, ref, model, seed)
     common.log(f"setup: params made and packed in {time.perf_counter() - t:.1f} s")
     eng = Engine(arch, params, EngineConfig(**conf["engine"]))
     if eng.ecfg.fused:
@@ -388,7 +400,8 @@ def run(ctx: dict) -> tuple[dict, dict, dict]:
     """One run of a serving cell -> (result without metrics, e2e, compared)."""
     cell, conf, mix, args = ctx["cell"], ctx["config"], ctx["traffic"], ctx["args"]
     arch, dev = ctx["arch"], ctx["device"]
-    model, eng = setup(arch, conf, mix, args.seed)
+    ctx["ref"] = ref = reference(conf)
+    model, eng = setup(arch, ref, conf, mix, args.seed)
     rate = cell["rate_per_s"]
     reqs = common.load_module("traffic", mix["generator"]).generate(
         mix, rate=rate, seconds=args.seconds, seed=args.seed, vocab=model["vocab_size"])
@@ -429,7 +442,7 @@ def run(ctx: dict) -> tuple[dict, dict, dict]:
 
     picked = sample(recs, args.seed, cell["sample"]["min_tokens"], cell["sample"]["max_requests"])
     t = time.perf_counter()
-    gap = check(model, args.seed, picked, conf["engine"]["max_seq_len"])
+    gap = check(ref, model, args.seed, picked, conf["engine"]["max_seq_len"])
     common.log(f"reference: {len(picked)} requests, {sum(len(r['tokens']) for r in picked)} "
                f"served tokens compared in {time.perf_counter() - t:.1f} s")
     unfinished = sum(not r["ok"] for r in recs)
@@ -448,7 +461,7 @@ def sweep(ctx: dict, rates: list[float]) -> None:
     whose queue does not grow through the window (printed per rate, no
     result line)."""
     cell, conf, mix, args = ctx["cell"], ctx["config"], ctx["traffic"], ctx["args"]
-    model, eng = setup(ctx["arch"], conf, mix, args.seed)
+    model, eng = setup(ctx["arch"], reference(conf), conf, mix, args.seed)
     gen = common.load_module("traffic", mix["generator"])
     for k, rate in enumerate(rates):
         reqs = gen.generate(mix, rate=rate, seconds=args.seconds, seed=args.seed + k,

@@ -1,17 +1,17 @@
 """Whole serving step: model operations of every request the run served
-(its prompt's prefill and each token decoded after the first,
-``bench/roofline/dense_gqa.py``) over the summed host spans of the
-dispatching steps that did the work -- the window's and the drain's --
-against the chip's bfloat16 peak.  Time the engine spends waiting for
-arrivals is left out, so a faster step reads higher whatever the offered
-load; it bounds every kernel's share of the step."""
+(its prompt's prefill and each token decoded after the first, counted by
+``bench/roofline/<name>.py`` for the reference the configuration names)
+over the summed host spans of the dispatching steps that did the work --
+the window's and the drain's -- against the chip's bfloat16 peak.  Time
+the engine spends waiting for arrivals is left out, so a faster step
+reads higher whatever the offered load; it bounds every kernel's share of
+the step."""
 from bench import common
-
-ops = common.load_module("roofline", "dense_gqa")
 
 
 def read(ctx):
     win, model = ctx["window"], ctx["model"]
+    ops = common.load_module("roofline", ctx["config"]["reference"])
     busy = sum(b - a for a, b in win["steps"])
     total = 0.0
     for r in win["records"]:

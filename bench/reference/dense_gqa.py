@@ -16,10 +16,12 @@ where the published code rotates ``(i, i + d/2)`` (the same rotation up to a
 fixed permutation of the q/k output columns).  The RMSNorm epsilon is the
 configuration's own (``rms_norm_eps``).
 
-Weights come from ``bench.weights`` one layer at a time, from the seed, so
-a full-width model's reference fits on one chip.  Every matmul runs at
-``precision="highest"``; ``rounding`` rounds the inputs of every matmul to a
-lower type first (the control: ``float8_e4m3fn``).
+It exposes what the serving harness asks of a configuration's reference
+(``bench/reference/__init__.py``): ``dims``, ``leaves``, ``Weights`` and
+``served_gaps``.  Weights come from ``bench.weights`` one layer at a time,
+from the seed, so a full-width model's reference fits on one chip.  Every
+matmul runs at ``precision="highest"``; ``rounding`` rounds the inputs of
+every matmul to a lower type first (the control: ``float8_e4m3fn``).
 """
 from __future__ import annotations
 
@@ -29,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights as W
+from bench import common, weights as W
 
 
 def _round(a: jax.Array, rounding) -> jax.Array:
@@ -82,6 +84,34 @@ def _head(x, g, w, eps, rounding):
     return _mm(_rmsnorm(x, g, eps), w, rounding)
 
 
+def dims(conf: dict) -> dict:
+    """The sizes this reference and its operation count
+    (``bench/roofline/dense_gqa.py``) use, from a config file's HF keys."""
+    return common.model_dims(conf)
+
+
+def leaves(dims: dict) -> list[tuple[str, tuple[int, ...], bool]]:
+    """(name, shape, layer-stacked) of every leaf of the program's parameter
+    tree for a dense GQA decoder: one segment of layer-stacked blocks."""
+    L, d, f, v = dims["n_layers"], dims["d_model"], dims["d_ff"], dims["vocab_size"]
+    hd = dims["head_dim"]
+    q_out, kv_out = dims["n_heads"] * hd, dims["n_kv_heads"] * hd
+    return [
+        ("embed/table", (v, d), False),
+        ("final_norm/g", (d,), False),
+        ("head/w", (d, v), False),
+        ("segments/0/attn/wk", (L, d, kv_out), True),
+        ("segments/0/attn/wo", (L, q_out, d), True),
+        ("segments/0/attn/wq", (L, d, q_out), True),
+        ("segments/0/attn/wv", (L, d, kv_out), True),
+        ("segments/0/ln1/g", (L, d), True),
+        ("segments/0/ln2/g", (L, d), True),
+        ("segments/0/mlp/wi_gate", (L, d, f), True),
+        ("segments/0/mlp/wi_up", (L, d, f), True),
+        ("segments/0/mlp/wo", (L, f, d), True),
+    ]
+
+
 class Weights:
     """The seeded weights of one configuration, made one leaf slice at a time."""
 
@@ -92,7 +122,7 @@ class Weights:
     def __init__(self, model: dict, seed: int):
         self.model = model
         self.key = W.base_key(seed)
-        self.leaves = {leaf["name"]: leaf for leaf in W.layout(model)}
+        self.leaves = {leaf["name"]: leaf for leaf in W.layout(leaves(model))}
         self._fns = {}
 
     def get(self, name: str, layer: int = 0) -> jax.Array:

@@ -55,7 +55,7 @@ def grid_step(fan_in: int) -> float:
 
 
 def grid_slice(key: jax.Array, layer, shape: tuple[int, ...], *, gain: bool = False):
-    """(q int32, sign int32) of one [K, N] (or [N] gain) slice of a grid tensor.
+    """(q int32, sign int32) of one [..., K, N] (or [N] gain) slice of a grid tensor.
 
     ``layer`` may be traced.  Gains sit at the top level (q = 1023, weight
     ~1); matrices take ``q = 2 * |bell|`` (0..1020) with the bell's sign, and
@@ -89,54 +89,47 @@ def dense_slice(key: jax.Array, shape: tuple[int, ...], *, gain: bool = False) -
 
 
 # ---------------------------------------------------------------------------
-# Layout of a dense GQA decoder, and the rule for what is on the grid
+# Layout of a parameter tree, and the rule for what is on the grid
 # ---------------------------------------------------------------------------
 
 MIN_GRID_SIZE = 4096  # tensors smaller than this are not deployed (planner default)
 
 
-def layout(model: dict) -> list[dict]:
-    """Every leaf of a dense GQA decoder with layer-stacked blocks.
+def layout(leaves: list[tuple[str, tuple[int, ...], bool]]) -> list[dict]:
+    """Every leaf of a parameter tree, with what the planner makes of it.
 
-    ``model`` holds the configuration's sizes (``n_layers``, ``d_model``,
-    ``n_heads``, ``n_kv_heads``, ``head_dim``, ``d_ff``, ``vocab_size``).
+    ``leaves`` holds ``(name, shape, stacked)`` for each leaf, as a
+    configuration's reference declares them (``bench/reference/<name>.py``
+    ``leaves``); a stacked leaf's first axis is the layer, and its slice
+    (one layer's share) may keep further leading axes, such as experts.
     A leaf is on the grid when the planner would deploy it: at least 2-D,
-    at least ``MIN_GRID_SIZE`` elements, and not the embedding table.
+    at least ``MIN_GRID_SIZE`` elements, and not the embedding table.  A
+    name ending in ``/g`` is a norm gain.  A grid matrix's step follows its
+    fan-in, the second-to-last axis of its slice (the contraction axis K of
+    ``[..., K, N]``).
     """
-    L, d, f, v = model["n_layers"], model["d_model"], model["d_ff"], model["vocab_size"]
-    hd = model["head_dim"]
-    q_out, kv_out = model["n_heads"] * hd, model["n_kv_heads"] * hd
-    leaves = [
-        ("embed/table", (v, d), False),
-        ("final_norm/g", (d,), False),
-        ("head/w", (d, v), False),
-        ("segments/0/attn/wk", (L, d, kv_out), True),
-        ("segments/0/attn/wo", (L, q_out, d), True),
-        ("segments/0/attn/wq", (L, d, q_out), True),
-        ("segments/0/attn/wv", (L, d, kv_out), True),
-        ("segments/0/ln1/g", (L, d), True),
-        ("segments/0/ln2/g", (L, d), True),
-        ("segments/0/mlp/wi_gate", (L, d, f), True),
-        ("segments/0/mlp/wi_up", (L, d, f), True),
-        ("segments/0/mlp/wo", (L, f, d), True),
-    ]
     out = []
     for name, shape, stacked in leaves:
+        shape = tuple(shape)
         size = math.prod(shape)
         gain = name.endswith("/g")
         grid = len(shape) >= 2 and size >= MIN_GRID_SIZE and "embed" not in name
         slice_shape = shape[1:] if stacked else shape
+        step = 2.0**-10
+        if grid and not gain:
+            step = grid_step(slice_shape[-2] if len(slice_shape) >= 2 else slice_shape[0])
         out.append({
             "name": name, "shape": shape, "stacked": stacked, "gain": gain, "grid": grid,
-            "slice": slice_shape,
-            "step": grid_step(slice_shape[0]) if grid and not gain else 2.0**-10,
+            "slice": slice_shape, "step": step,
         })
     return out
 
 
 def leaf_slice(key: jax.Array, leaf: dict, layer) -> jax.Array:
     """float32 value of one layer's slice of ``leaf`` (the whole leaf when
-    it is not layer-stacked)."""
+    it is not layer-stacked), drawn in one call whatever leading axes the
+    slice keeps: a grid tensor holds ``q = 1023`` at its element 0 of layer
+    0 only, so its largest magnitude is ``1023 * step`` across all of them."""
     k = leaf_key(key, leaf["name"])
     if leaf["grid"]:
         q, sign = grid_slice(k, layer, leaf["slice"], gain=leaf["gain"])

@@ -105,7 +105,7 @@ def test_serving_mfu_counts_the_work_over_the_steps():
     work = (gqa.prefill_ops(model, 4) + gqa.decode_ops(model, 5) + gqa.decode_ops(model, 6)
             + gqa.prefill_ops(model, 10))
     peaks = common.peaks_for("TPU v5 lite")
-    ctx = {"model": model, "peaks": peaks,
+    ctx = {"model": model, "config": {"reference": "dense_gqa"}, "peaks": peaks,
            "window": {"records": [a, b, idle], "steps": [(0.0, 0.5), (2.0, 2.25)],
                       "seconds": 51.0}}
     assert read(ctx) == pytest.approx(100.0 * work / 0.75 / 197e12)

@@ -2,6 +2,8 @@
 look for a chip skipped), and the comparison passes the sound program and
 fails the lower-precision control and each fault the cell can have."""
 import dataclasses
+import json
+import shutil
 import time
 import types
 
@@ -29,7 +31,7 @@ def _serve_ctx(seed):
             "num_attention_heads": arch.n_heads, "num_key_value_heads": arch.n_kv_heads,
             "intermediate_size": arch.d_ff, "vocab_size": arch.vocab_size,
             "rope_theta": arch.rope_theta, "rms_norm_eps": 1e-5,
-            "assumed": {"head_dim": arch.resolved_head_dim},
+            "assumed": {"head_dim": arch.resolved_head_dim}, "reference": "dense_gqa",
             "engine": {"max_slots": 2, "page_size": 8, "max_seq_len": 64, "prefill_chunk": 16,
                        "decode_quantum": 4, "fused": False}}
     mix = {"generator": "open_loop", "driver": "serve",
@@ -67,6 +69,52 @@ def test_serving_host_readers_read_a_real_window(sound_serve, name):
     assert value is not None and 0 < value < (100 if name.startswith("mfu") else 1e5)
 
 
+_SPY = """
+from bench import common
+
+_real = common.load_module("reference", "dense_gqa")
+CALLS = []
+
+
+def dims(conf):
+    CALLS.append("dims")
+    return _real.dims(conf)
+
+
+def leaves(dims):
+    CALLS.append("leaves")
+    return _real.leaves(dims)
+
+
+class Weights(_real.Weights):
+    def __init__(self, dims, seed):
+        CALLS.append("Weights")
+        super().__init__(dims, seed)
+
+
+def served_gaps(*args, **kwargs):
+    CALLS.append("served_gaps")
+    return _real.served_gaps(*args, **kwargs)
+"""
+
+
+def test_the_driver_serves_the_reference_its_configuration_names(tmp_path, monkeypatch):
+    """A spy reference, named by a configuration written for the test, is
+    the module whose sizes and leaves set the run up and whose weights and
+    gaps decide ``correct``: the driver holds no reference of its own."""
+    shutil.copytree(common.BENCH, tmp_path / "bench")
+    b = tmp_path / "bench"
+    (b / "reference" / "spy.py").write_text(_SPY)
+    ctx = _serve_ctx(2**31 + 17)
+    (b / "configs" / "spy.json").write_text(json.dumps({**ctx["config"], "reference": "spy"}))
+    monkeypatch.setattr(common, "BENCH", b)
+    ctx["config"] = common.load_json("configs", "spy")
+    result, _, compared = serve.run(ctx)
+    assert ctx["ref"].__file__ == str(b / "reference" / "spy.py")
+    assert ctx["ref"].CALLS == ["dims", "leaves", "Weights", "served_gaps"]
+    assert result["correct"], compared
+
+
 def _long_prompt_ctx(seed):
     """A run's sample as the control reads it, at the reduced width but with
     the chat cell's long prompts (1800 tokens): float8 rounds attention
@@ -81,7 +129,8 @@ def _long_prompt_ctx(seed):
     recs = [{"rid": i, "ok": True, "prompt": rng.integers(0, vocab, 1800).astype(np.int32),
              "tokens": [int(t) for t in rng.integers(0, vocab, 16)]} for i in range(2)]
     ctx["window"] = {"records": recs}
-    ctx["model"] = common.model_dims(ctx["config"])
+    ctx["ref"] = serve.reference(ctx["config"])
+    ctx["model"] = ctx["ref"].dims(ctx["config"])
     ctx["config"]["engine"]["max_seq_len"] = 1824
     ctx["cell"]["sample"] = {"min_tokens": 32, "max_requests": 2}
     return ctx
